@@ -38,24 +38,22 @@ def _provenance() -> dict:
     """Stamp for refreshed sections: which software/hardware produced the
     timings (jax/jaxlib versions, device kind and count, platform, git
     commit) — so a BENCH_engine.json diff is interpretable months later
-    without spelunking CI logs."""
+    without spelunking CI logs.  A failure to read the devices raises:
+    a timing without its device is not recorded."""
     import platform
     import subprocess
 
-    info: dict = {"python": platform.python_version(),
-                  "platform": platform.platform()}
-    try:
-        import jax
-        import jaxlib
+    import jax
+    import jaxlib
 
-        devs = jax.devices()
-        info.update(jax=jax.__version__, jaxlib=jaxlib.__version__,
-                    backend=jax.default_backend(),
-                    device_kind=devs[0].device_kind,
-                    device_count=len(devs))
-    except Exception:                                     # noqa: BLE001
-        pass                  # provenance is best-effort, never fatal
-    try:
+    devs = jax.devices()
+    info: dict = {"python": platform.python_version(),
+                  "platform": platform.platform(),
+                  "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                  "backend": devs[0].platform,
+                  "device_kind": devs[0].device_kind,
+                  "device_count": len(devs)}
+    try:                      # a copy of the tree need not be a git checkout
         out = subprocess.run(
             ["git", "rev-parse", "--short", "HEAD"], cwd=_REPO_ROOT,
             capture_output=True, text=True, timeout=10)
@@ -257,6 +255,9 @@ def _suites():
 
 
 def main(argv=None) -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     suites = _suites()
     by_name = {fn.__name__: fn for fn in suites}
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])

@@ -1,0 +1,31 @@
+"""JAX's persistent compilation cache, for the entry points.
+
+Called from ``chip_smoke.py``, ``benchmarks/run.py`` and
+``repro.launch.train`` — never when a module is imported, so tests and
+library users keep JAX's own defaults.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache``: the directory is part of what a cached entry
+    is found by, so a path made from a temporary name, a process id or
+    the time would never hit.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+    return REPO_CACHE_DIR

@@ -95,7 +95,10 @@ def detect_groups(symbols: jnp.ndarray, group_of_worker: jnp.ndarray,
     gid = jnp.where(valid, group_of_worker, 0)
     onehot = jax.nn.one_hot(gid, num_groups, dtype=symbols.dtype) * valid[:, None]
     count = onehot.sum(axis=0)                                   # (G,)
-    gsum = jnp.einsum("nk,ng->gk", symbols, onehot)
+    # f32 precision stated: at a TPU's default (bf16 passes) the group
+    # mean of identical replicas misses them by more than tau
+    gsum = jnp.einsum("nk,ng->gk", symbols, onehot,
+                      precision=jax.lax.Precision.HIGHEST)
     gmean = gsum / jnp.maximum(count, 1.0)[:, None]
     ref = gmean[gid]                                             # (n, k)
     scale = 1.0 + jnp.abs(ref)

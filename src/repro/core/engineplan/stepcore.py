@@ -67,6 +67,12 @@ TAU_DETECT = 1e-9     # matches the engine's absolute replica compare
 
 _PH1 = np.uint32(1 << 16)     # phase-1 counter bit (identify pass)
 
+# Every contraction below states full f32 precision.  On a TPU, XLA's
+# default for f32 operands multiplies in bf16 passes; that broke the
+# values contract with the float64 numpy engine (max deviation 1e-4)
+# on the chip, by up to 0.6 on the gram plane.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def shard_mask(shard, group, m, n_data):
     """(B, n) shard layout -> (B, n, I) f32 row-ownership mask.
@@ -177,7 +183,7 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
 
     def contract(cr):                  # (B, I) row weights -> (B, d)
         if shared:
-            return jnp.einsum("bi,id->bd", cr, A)
+            return jnp.einsum("bi,id->bd", cr, A, precision=HIGHEST)
         return ops.batched_coded_encode(cr[:, None, :], A, impl=impl)[:, 0]
 
     def agg(agg_coeff, tam, mask, cr_base):
@@ -190,7 +196,8 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
         the next contraction — the fused kernel's, or the gram carry's
         — to apply."""
         aeff = jnp.where(tam, alpha[:, None], 1.0) * agg_coeff
-        row = jnp.einsum("bw,bwi->bi", aeff, mask) * cr_base
+        row = jnp.einsum("bw,bwi->bi", aeff, mask,
+                         precision=HIGHEST) * cr_base
         if coeff:
             tw = agg_coeff * tam
             return row, (tw * beta[:, None]).sum(axis=1), \
@@ -211,9 +218,9 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
         (B, I, k) otherwise (per-problem tables gathered by ``pid``)."""
         C = mask * cr_base[:, None, :]                       # (B, n, I)
         if coeff:
-            skw = jnp.einsum("bwi,ik->bwk", C, SA_b)
+            skw = jnp.einsum("bwi,ik->bwk", C, SA_b, precision=HIGHEST)
         else:
-            skw = jnp.einsum("bwi,bik->bwk", C, SA_b)
+            skw = jnp.einsum("bwi,bik->bwk", C, SA_b, precision=HIGHEST)
         if coeff or has_bias:
             add = beta[:, None, None] * sk_one[None, None] \
                 + nu[:, None, None] * sk_noise[None, None]
@@ -270,11 +277,14 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
 
             if gram:
                 resid = S0n - jnp.dot(
-                    W, Gn, preferred_element_type=jnp.float32) - y[None, :]
+                    W, Gn, precision=HIGHEST,
+                    preferred_element_type=jnp.float32) - y[None, :]
             elif shared:
-                resid = jnp.einsum("id,bd->bi", A, W) - y[None, :]
+                resid = jnp.einsum("id,bd->bi", A, W,
+                                   precision=HIGHEST) - y[None, :]
             else:
-                resid = jnp.einsum("bid,bd->bi", A, W) - y
+                resid = jnp.einsum("bid,bd->bi", A, W,
+                                   precision=HIGHEST) - y
             loss = (resid * resid).mean(axis=1)
 
             # -- q*_t and the check coin (rngstream DECIDE) ------------
@@ -406,6 +416,7 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
         if gram:
             # the only d-sized work of the whole run: W_T = W0 - C_T @ R
             W = W0 - jnp.dot(W, A["rows"].astype(jnp.float32),
+                             precision=HIGHEST,
                              preferred_element_type=jnp.float32)
         losses, q_tr, check_tr, det_tr, faulty2_tr = ys
         if telemetry:
@@ -434,16 +445,19 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
             W = carry                                        # C_t (B, Ie)
             x, c = xc
             resid = S0n - jnp.dot(
-                W, Gn, preferred_element_type=jnp.float32) - y[None, :]
+                W, Gn, precision=HIGHEST,
+                preferred_element_type=jnp.float32) - y[None, :]
             SA_b = c["SA"]
             sk_one, sk_noise = c["sk_one"], c["sk_noise"]
         else:
             W = carry
             x, c = xc
             if shared:
-                resid = jnp.einsum("id,bd->bi", A, W) - y[None, :]
+                resid = jnp.einsum("id,bd->bi", A, W,
+                                   precision=HIGHEST) - y[None, :]
             else:
-                resid = jnp.einsum("bid,bd->bi", A, W) - y
+                resid = jnp.einsum("bid,bd->bi", A, W,
+                                   precision=HIGHEST) - y
             SA_b = c["SA"][pid]
             sk_one, sk_noise = c["sk_one"], c["sk_noise"]
         loss = (resid * resid).mean(axis=1)
@@ -510,9 +524,9 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
         if has_filter:
             C = mask1 * cr1[:, None, :]
             if shared:
-                g1 = jnp.einsum("bwi,id->bwd", C, A)
+                g1 = jnp.einsum("bwi,id->bwd", C, A, precision=HIGHEST)
             else:
-                g1 = jnp.einsum("bwi,bid->bwd", C, A)
+                g1 = jnp.einsum("bwi,bid->bwd", C, A, precision=HIGHEST)
             gt1 = apply_affine(g1, x["tam1"], alpha, beta, nu, noisevec,
                                has_bias)
             act = x["active"] & x["live"][:, None]
@@ -574,11 +588,12 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
     if fused:
         W, cw = fin
         # the last step's update is still pending: one final contraction
-        W = W - jnp.dot(cw, A.astype(jnp.float32),
+        W = W - jnp.dot(cw, A.astype(jnp.float32), precision=HIGHEST,
                         preferred_element_type=jnp.float32)
     elif gram:
         # the only d-sized work of the whole run: W_T = W0 - C_T @ R
         W = W0 - jnp.dot(fin, A["rows"].astype(jnp.float32),
+                         precision=HIGHEST,
                          preferred_element_type=jnp.float32)
     else:
         W = fin

@@ -21,7 +21,9 @@ BLOCK_D = 2048
 def _encode_kernel(c_ref, g_ref, o_ref):
     c = c_ref[...].astype(jnp.float32)                    # (n_sym, m)
     g = g_ref[...].astype(jnp.float32)                    # (m, BD)
-    o_ref[...] = jnp.dot(c, g, preferred_element_type=jnp.float32)
+    # f32 precision stated: Mosaic's default multiplies f32 in bf16 passes
+    o_ref[...] = jnp.dot(c, g, precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
@@ -51,7 +53,8 @@ def coded_encode(coeffs: jnp.ndarray, grads: jnp.ndarray,
 def _encode_kernel_batched(c_ref, g_ref, o_ref):
     c = c_ref[0].astype(jnp.float32)                      # (n_sym, m)
     g = g_ref[0].astype(jnp.float32)                      # (m, BD)
-    o_ref[0] = jnp.dot(c, g, preferred_element_type=jnp.float32)
+    o_ref[0] = jnp.dot(c, g, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))

@@ -55,13 +55,17 @@ def _fused_step_kernel(rows_ref, w_ref, cw_ref, key_ref,
     w = w_ref[...]                                         # (B, bd)
     cw = cw_ref[...]                                       # (B, Ie)
 
-    # (a) pending update: W' = W - cw @ rows, written back in place
-    upd = jnp.dot(cw, rows, preferred_element_type=jnp.float32)
+    # (a) pending update: W' = W - cw @ rows, written back in place.
+    # Both dots state f32 precision: Mosaic's default multiplies f32 in
+    # bf16 passes, which misses the engine's parity contract
+    upd = jnp.dot(cw, rows, precision=jax.lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)
     w_new = w - upd
     w_out_ref[...] = w_new
 
     # (b) residual symbols of the NEW iterate: resid += W' @ rows^T
     pres = jax.lax.dot_general(w_new, rows, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
 
     # (c) CountSketch of the data rows: signs rematerialized in-register

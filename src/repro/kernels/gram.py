@@ -59,15 +59,19 @@ def _gram_factors_kernel(*refs, t_count: int, k: int, block_d: int,
             s0_ref[...] = jnp.zeros_like(s0_ref)
         sk_ref[...] = jnp.zeros_like(sk_ref)
 
-    # (a) Gram block: G += rows @ rows^T over this d-slab
+    # (a) Gram block: G += rows @ rows^T over this d-slab.  Both dots
+    # state f32 precision: Mosaic's default multiplies f32 in bf16
+    # passes, and every residual of the gram scan is read off G
     g_ref[...] += jax.lax.dot_general(
         rows, rows, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
     # (b) starting symbols: S0 += W0 @ rows^T
     if has_w0:
         s0_ref[...] += jax.lax.dot_general(
             w0_ref[...], rows, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
     # (c) per-step CountSketch tables: signs rematerialized in-register

@@ -1,8 +1,9 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-On CPU (this container) every entry point takes ``interpret=True``; on TPU
-the same call sites compile to Mosaic.  ``INTERPRET`` defaults to True when
-no TPU is present so library code can call these unconditionally.
+Off a TPU every entry point runs its kernel in Pallas interpret mode; on
+a TPU the same call sites compile to Mosaic.  The choice is made when an
+op is called (``interpret=None``), never when this module is imported, so
+importing ``repro`` initializes no backend.
 
 The ``batched_*`` ops (leading trial dimension) additionally carry an
 ``impl`` switch because they sit on the jitted scenario engine's hot
@@ -35,7 +36,18 @@ from repro.kernels import majority_vote as _mv
 from repro.kernels import ref as _ref
 from repro.kernels import sketch as _sk
 
-INTERPRET = jax.default_backend() != "tpu"
+# the XLA fallbacks state f32 precision, as the kernels do: a TPU's
+# default for f32 operands multiplies in bf16 passes
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _interpret(interpret: bool | None) -> bool:
+    """Interpret mode unless asked otherwise or running on a TPU."""
+    return not _on_tpu() if interpret is None else interpret
 
 
 def _shard_batched(kernel, args, arg_specs, out_spec):
@@ -84,7 +96,7 @@ def resolve_impl(impl: str | None) -> str:
     if impl is None:
         env = os.environ.get("REPRO_KERNEL_IMPL") or None
         if env is None:
-            return "xla" if INTERPRET else "pallas"
+            return "pallas" if _on_tpu() else "xla"
         if env not in _IMPL_CHOICES:
             raise ValueError(
                 f"REPRO_KERNEL_IMPL={env!r} is not a known kernel impl; "
@@ -104,13 +116,13 @@ _batched_impl = resolve_impl
 def sketch(flat_g, key_scalar, k: int = 256, interpret: bool | None = None):
     return _sk.sketch(
         flat_g, key_scalar, k=k,
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=_interpret(interpret),
     )
 
 
 def pairwise_relmax(replicas, interpret: bool | None = None):
     return _mv.pairwise_relmax(
-        replicas, interpret=INTERPRET if interpret is None else interpret
+        replicas, interpret=_interpret(interpret)
     )
 
 
@@ -134,7 +146,7 @@ def vote(replicas, tau: float = 1e-5, interpret: bool | None = None):
 
 def coded_encode(coeffs, grads, interpret: bool | None = None):
     return _enc.coded_encode(
-        coeffs, grads, interpret=INTERPRET if interpret is None else interpret
+        coeffs, grads, interpret=_interpret(interpret)
     )
 
 
@@ -148,7 +160,7 @@ def batched_pairwise_relmax(replicas, *, impl: str | None = None,
     if _batched_impl(impl) == "pallas":
         kern = functools.partial(
             _mv.pairwise_relmax_batched,
-            interpret=INTERPRET if interpret is None else interpret,
+            interpret=_interpret(interpret),
         )
         return _shard_batched(kern, (replicas.astype(jnp.float32),),
                               (True,), 3)
@@ -274,7 +286,7 @@ def batched_coded_encode(coeffs, grads, *, impl: str | None = None,
     if _batched_impl(impl) == "pallas":
         kern = functools.partial(
             _enc.coded_encode_batched,
-            interpret=INTERPRET if interpret is None else interpret,
+            interpret=_interpret(interpret),
         )
         return _shard_batched(kern, (coeffs, grads), (True, True), 3)
     return _ref.batched_coded_encode_ref(coeffs, grads)
@@ -286,7 +298,7 @@ def batched_sketch(flat_g, key_scalar, k: int = 256, *,
     if _batched_impl(impl) == "pallas":
         kern = functools.partial(
             _sk.sketch_batched, k=k,
-            interpret=INTERPRET if interpret is None else interpret,
+            interpret=_interpret(interpret),
         )
         return _shard_batched(kern, (flat_g, jnp.asarray(key_scalar)),
                               (True, False), 2)
@@ -311,7 +323,7 @@ def fused_step(rows, W, cw, key_scalar, *, k: int = 256,
     if _batched_impl(impl) == "pallas":
         kern = functools.partial(
             _fs.fused_step, k=k,
-            interpret=INTERPRET if interpret is None else interpret,
+            interpret=_interpret(interpret),
         )
         from jax.sharding import PartitionSpec as P
 
@@ -336,8 +348,9 @@ def fused_step(rows, W, cw, key_scalar, *, k: int = 256,
 def _fused_step_xla(rows, W, cw, key_scalar, k):
     rows32 = rows.astype(jnp.float32)
     W_new = W.astype(jnp.float32) - jnp.dot(
-        cw, rows32, preferred_element_type=jnp.float32)
+        cw, rows32, precision=_HIGHEST, preferred_element_type=jnp.float32)
     resid = jax.lax.dot_general(W_new, rows32, (((1,), (1,)), ((), ())),
+                                precision=_HIGHEST,
                                 preferred_element_type=jnp.float32)
     Ie, d = rows32.shape
     pad = (-d) % k
@@ -372,7 +385,7 @@ def gram_factors(rows, W0, keys, *, k: int = 256,
     """
     keys = jnp.asarray(keys, jnp.uint32)
     if _batched_impl(impl) == "pallas":
-        interp = INTERPRET if interpret is None else interpret
+        interp = _interpret(interpret)
         (T,) = keys.shape
         if T == 0:
             G, S0, _ = _gm.gram_factors(rows, W0,
@@ -400,10 +413,11 @@ def gram_factors(rows, W0, keys, *, k: int = 256,
 def _gram_factors_xla(rows, W0, keys, k):
     rows32 = rows.astype(jnp.float32)
     G = jax.lax.dot_general(rows32, rows32, (((1,), (1,)), ((), ())),
+                            precision=_HIGHEST,
                             preferred_element_type=jnp.float32)
     S0 = None if W0 is None else jax.lax.dot_general(
         W0.astype(jnp.float32), rows32, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
     Ie, d = rows32.shape
     pad = (-d) % k
     g = jnp.pad(rows32, ((0, 0), (0, pad)))
@@ -421,6 +435,7 @@ def _gram_factors_xla(rows, W0, keys, k):
         signs = jax.vmap(lambda key: _ref.hash_signs_ref(idx, key))(keys)
         SK = jnp.einsum("imb,tmb->tib", g.reshape(Ie, -1, k),
                         signs.reshape(keys.shape[0], -1, k),
+                        precision=_HIGHEST,
                         preferred_element_type=jnp.float32)
     return G, S0, SK
 
@@ -440,5 +455,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     interpret: bool | None = None):
     return _fa.flash_attention(
         q, k, v, causal=causal, window=window, scale=scale, bq=bq, bk=bk,
-        interpret=INTERPRET if interpret is None else interpret,
+        interpret=_interpret(interpret),
     )

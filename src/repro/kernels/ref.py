@@ -72,7 +72,8 @@ def majority_vote_ref(replicas: jnp.ndarray, tau: float):
 def coded_encode_ref(coeffs: jnp.ndarray, grads: jnp.ndarray) -> jnp.ndarray:
     """coeffs (n_sym, m) @ grads (m, d) -> symbols (n_sym, d), f32 accum."""
     return jnp.einsum(
-        "sm,md->sd", coeffs.astype(jnp.float32), grads.astype(jnp.float32)
+        "sm,md->sd", coeffs.astype(jnp.float32), grads.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -124,7 +125,8 @@ def batched_coded_encode_ref(coeffs: jnp.ndarray,
                              grads: jnp.ndarray) -> jnp.ndarray:
     """(B, n_sym, m) @ (B, m, d) -> (B, n_sym, d), f32 accum."""
     return jnp.einsum(
-        "bsm,bmd->bsd", coeffs.astype(jnp.float32), grads.astype(jnp.float32)
+        "bsm,bmd->bsd", coeffs.astype(jnp.float32), grads.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 

@@ -20,6 +20,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, list_configs
 from repro.core.randomized import BFTConfig
 from repro.optim import OptConfig
@@ -54,6 +55,7 @@ def main() -> None:
     ap.add_argument("--restore", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     workers = args.workers or n_dev

@@ -17,87 +17,48 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # ---------------------------------------------------------------------------
-# jax-version compatibility: the ambient-mesh API surface moved between
-# jax releases (jax.sharding.AxisType / jax.set_mesh / use_mesh /
-# get_abstract_mesh landed after 0.4.37; the legacy spelling is the Mesh
-# context manager + thread_resources).  Everything in this repo goes
-# through the four shims below so either spelling works.
+# Mesh helpers: every mesh axis is explicitly Auto, so a change of JAX's
+# default axis type cannot silently change sharding behaviour.
 # ---------------------------------------------------------------------------
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               devices=None) -> Mesh:
-    """``jax.make_mesh`` with explicit-Auto axis types where supported.
-
-    Old jax has no ``axis_types`` kwarg (every axis is implicitly Auto);
-    new jax defaults to Auto too, but we pass it explicitly so a future
-    default flip cannot silently change sharding behavior.
-    """
+    """``jax.make_mesh`` with every axis typed Auto."""
     kwargs: dict[str, Any] = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kwargs)
 
 
 def set_mesh(mesh: Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh
-    (``jax.set_mesh`` / ``jax.sharding.use_mesh`` / legacy Mesh context)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh  # legacy: Mesh is itself a context manager
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def ambient_mesh():
     """The ambient (abstract) mesh, or None when no mesh is installed."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        mesh = get()
-        if mesh is None or not mesh.axis_names:
-            return None
-        return mesh
-    from jax._src import mesh as _mesh_lib
-
-    mesh = _mesh_lib.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
+        return None
+    return mesh
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool | None = None):
-    """``jax.shard_map`` compat: new API when present, else the
-    experimental spelling (``axis_names`` -> ``auto`` complement,
-    ``check_vma`` -> ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        kw: dict[str, Any] = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {}
+    """``jax.shard_map``; ``axis_names`` (manual axes) and ``check_vma``
+    are passed on only when given."""
+    kw: dict[str, Any] = {}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
+        kw["axis_names"] = set(axis_names)
     if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
-def _bound_axis_names() -> frozenset:
-    """Mesh axis names bound in the current trace's axis env (old-jax
-    spelling of "consumed by an enclosing shard_map").  New jax encodes
-    this in ``mesh.axis_types`` instead; there the env is not consulted."""
-    try:
-        from jax._src import core as _core
-
-        return frozenset(_core.get_axis_env().axis_names())
-    except Exception:
-        return frozenset()
+def _is_auto(axis_type) -> bool:
+    return axis_type == jax.sharding.AxisType.Auto
 
 
 def trials_mesh(max_devices: int | None = None) -> Mesh | None:
@@ -300,15 +261,9 @@ def mesh_axis_size_here(name: str) -> int:
             mesh.shape.values() if isinstance(mesh.shape, dict) else mesh.shape,
         )
     )
-    types = getattr(mesh, "axis_types", None)
-    if types is not None:
-        for n, t in zip(mesh.axis_names, types):
-            if n == name and not (
-                str(t) == "Auto" or getattr(t, "name", "") == "Auto"
-            ):
-                return 1
-    elif name in _bound_axis_names():
-        return 1  # old jax: bound in the trace env => consumed/manual
+    for n, t in zip(mesh.axis_names, mesh.axis_types):
+        if n == name and not _is_auto(t):
+            return 1
     return int(sizes.get(name, 1))
 
 
@@ -323,19 +278,9 @@ def constrain_here(x, logical: Sequence[str | None]):
     sizes = dict(zip(mesh.axis_names, mesh.shape.values() if isinstance(mesh.shape, dict) else mesh.shape))
     # inside a shard_map body some axes are Manual — constraints may only
     # name Auto axes (the worker axes are already consumed by shard_map)
-    types = getattr(mesh, "axis_types", None)
-    if types is not None:
-        auto = {
-            n for n, t in zip(mesh.axis_names, types)
-            if str(t) == "Auto" or getattr(t, "name", "") == "Auto"
-        }
-        sizes = {n: s for n, s in sizes.items() if n in auto}
-    else:
-        # old jax: inside a shard_map every mesh axis is bound in the
-        # trace env and constraints naming them are rejected — drop them
-        # (GSPMD still propagates shardings from the operands)
-        bound = _bound_axis_names()
-        sizes = {n: s for n, s in sizes.items() if n not in bound}
+    auto = {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if _is_auto(t)}
+    sizes = {n: s for n, s in sizes.items() if n in auto}
     if not sizes:
         return x
 
