@@ -71,6 +71,7 @@ from repro.core.assignment import (
 )
 from repro.core.identification import majority_vote_np
 from repro.core.randomized import BFTConfig, ProtocolState, decide_generator
+from repro.obs import trace as obtrace
 from repro.obs.telemetry import Telemetry, zero_counts
 
 # ---------------------------------------------------------------------------
@@ -854,9 +855,12 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
             dirty_trials = []
 
         # -- losses (shared residual also feeds the gradients) ------------
-        resid = residuals(A_b, y_b, W, out=resid_buf)        # (B, I)
-        loss_col = losses_of(resid)                          # (B,)
-        losses_mat[:, t] = loss_col
+        # every float64 product over the problem's data runs under a
+        # numpy.data span; the control work between them stays outside
+        with obtrace.span("numpy.data"):
+            resid = residuals(A_b, y_b, W, out=resid_buf)    # (B, I)
+            loss_col = losses_of(resid)                      # (B,)
+            losses_mat[:, t] = loss_col
 
         # -- check decisions ----------------------------------------------
         if vec_all:
@@ -929,29 +933,30 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
         m_all = batch_a.num_shards
 
         # -- shard gradients: one batched matmul per distinct m -----------
-        for m in np.unique(m_all if live_all else m_all[live]):
-            m = int(m)
-            is_m = m_all == m
-            if not live_all:
-                is_m &= live
-            sub = np.flatnonzero(is_m)
-            rows = n_data // m
-            if shared_problem:
-                Ar = A0[: m * rows].reshape(1, m, rows, d)
-            else:
-                Ar = A_b[sub, : m * rows].reshape(len(sub), m, rows, d)
-            rr = resid[sub, : m * rows].reshape(len(sub), m, 1, rows)
-            sg = shard_gradients(Ar, rr, rows)               # (S, m, d)
-            if m == n_max and (group_all[sub] >= 0).all():
-                # fast mode, nobody eliminated: worker w owns shard w —
-                # the gather is the identity, skip it
-                if sub.size == B:
-                    grads = sg
+        with obtrace.span("numpy.data"):
+            for m in np.unique(m_all if live_all else m_all[live]):
+                m = int(m)
+                is_m = m_all == m
+                if not live_all:
+                    is_m &= live
+                sub = np.flatnonzero(is_m)
+                rows = n_data // m
+                if shared_problem:
+                    Ar = A0[: m * rows].reshape(1, m, rows, d)
                 else:
-                    grads[sub] = sg
-            else:
-                grads[sub] = worker_gradients(sg, shard_all[sub],
-                                              group_all[sub])
+                    Ar = A_b[sub, : m * rows].reshape(len(sub), m, rows, d)
+                rr = resid[sub, : m * rows].reshape(len(sub), m, 1, rows)
+                sg = shard_gradients(Ar, rr, rows)               # (S, m, d)
+                if m == n_max and (group_all[sub] >= 0).all():
+                    # fast mode, nobody eliminated: worker w owns shard w —
+                    # the gather is the identity, skip it
+                    if sub.size == B:
+                        grads = sg
+                    else:
+                        grads[sub] = sg
+                else:
+                    grads[sub] = worker_gradients(sg, shard_all[sub],
+                                                  group_all[sub])
 
         # -- Byzantine tampering (phase 1) --------------------------------
         hits = streams.phase1_hits(t, live) if has_byz else None
@@ -995,14 +1000,15 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
                                           2 * max(1, int(f_t_arr[b])) + 1,
                                           st.rng)
                 rows = n_data // ai.num_shards
-                Ar = (A0 if shared_problem else A_b[b])[: ai.num_shards *
-                                                        rows]
-                Ar = Ar.reshape(1, ai.num_shards, rows, d)
-                rr = resid[b, : ai.num_shards * rows].reshape(
-                    1, ai.num_shards, 1, rows)
-                sg = shard_gradients(Ar, rr, rows)
-                g2 = worker_gradients(sg, ai.shard_of_worker[None],
-                                      ai.group_of_worker[None])[0]
+                with obtrace.span("numpy.data"):
+                    Ar = (A0 if shared_problem else A_b[b])[
+                        : ai.num_shards * rows]
+                    Ar = Ar.reshape(1, ai.num_shards, rows, d)
+                    rr = resid[b, : ai.num_shards * rows].reshape(
+                        1, ai.num_shards, 1, rows)
+                    sg = shard_gradients(Ar, rr, rows)
+                    g2 = worker_gradients(sg, ai.shard_of_worker[None],
+                                          ai.group_of_worker[None])[0]
                 tam = streams.phase2_hits(b, t)
                 if tam:
                     _apply_attacks(g2[None], np.zeros(len(tam), np.int64),
@@ -1090,10 +1096,11 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
             tel_np["byz_active_steps"] += np.where(
                 live, (byz_mask & bstate.active).sum(axis=1), 0)
 
-        grad_upd = aggregate(agg_weight, grads)
-        for b, v in voted.items():
-            grad_upd[b] = v
-        W = np.where(live[:, None], W - lr[:, None] * grad_upd, W)
+        with obtrace.span("numpy.data"):
+            grad_upd = aggregate(agg_weight, grads)
+            for b, v in voted.items():
+                grad_upd[b] = v
+            W = np.where(live[:, None], W - lr[:, None] * grad_upd, W)
 
     # -- materialize per-trial results ------------------------------------
     results = []
@@ -1470,25 +1477,27 @@ def replay_control_fast(specs: list[TrialSpec],
     # -- materialize control results (no float quantities) ----------------
     empty = np.zeros(0)
     results = []
-    for b, s in enumerate(specs):
-        tr, st = trials[b], trials[b].st
-        st.step = s.steps
-        meter = st.meter
-        meter.used = int(used_acc[b])
-        meter.computed = int(comp_acc[b])
-        meter.iterations = s.steps
-        meter.check_iterations = int(check_acc[b])
-        meter.identify_iterations = int(ident_acc[b])
-        meter.history = eff_hist[b, :s.steps].tolist()
-        st.last_q = float(q_trace_mat[b, s.steps - 1]) if s.steps else 0.0
-        results.append(SimResult(
-            w=empty,
-            w_true=empty,
-            state=st,
-            losses=[],
-            q_trace=q_trace_mat[b, :s.steps].tolist(),
-            identify_step=tr.ident_step,
-        ))
+    with obtrace.span("replay.materialize", B=B):
+        for b, s in enumerate(specs):
+            tr, st = trials[b], trials[b].st
+            st.step = s.steps
+            meter = st.meter
+            meter.used = int(used_acc[b])
+            meter.computed = int(comp_acc[b])
+            meter.iterations = s.steps
+            meter.check_iterations = int(check_acc[b])
+            meter.identify_iterations = int(ident_acc[b])
+            meter.history = eff_hist[b, :s.steps].tolist()
+            st.last_q = (float(q_trace_mat[b, s.steps - 1]) if s.steps
+                         else 0.0)
+            results.append(SimResult(
+                w=empty,
+                w_true=empty,
+                state=st,
+                losses=[],
+                q_trace=q_trace_mat[b, :s.steps].tolist(),
+                identify_step=tr.ident_step,
+            ))
     return BatchResult(specs, results, time.perf_counter() - t_start)
 
 

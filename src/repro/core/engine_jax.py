@@ -150,7 +150,8 @@ def build_schedule(specs: list[TrialSpec], mode: str = "auto") -> Schedule:
 
     rec = ScheduleRecorder()
     if mode == "vector":
-        control = replay_control_fast(specs, rec)
+        with obtrace.span("schedule.replay"):
+            control = replay_control_fast(specs, rec)
     else:
         if mode == "proxy":
             n_data = max(_PROXY_N_DATA, 2 * max(s.n for s in specs))
@@ -158,9 +159,11 @@ def build_schedule(specs: list[TrialSpec], mode: str = "auto") -> Schedule:
                           for s in specs]
         else:
             ctrl_specs = specs
-        control = run_batch(ctrl_specs, _recorder=rec)
-    keys = rec.steps[0].keys() if rec.steps else ()
-    arrays = {k: np.stack([st[k] for st in rec.steps]) for k in keys}
+        with obtrace.span("schedule.oracle", mode=mode):
+            control = run_batch(ctrl_specs, _recorder=rec)
+    with obtrace.span("schedule.stack"):
+        keys = rec.steps[0].keys() if rec.steps else ()
+        arrays = {k: np.stack([st[k] for st in rec.steps]) for k in keys}
     return Schedule(arrays, control, mode != "oracle", mode)
 
 
@@ -331,12 +334,75 @@ def run_batch_jax(specs, *, schedule: str = "auto",
     obmetrics.counter(f"engine.plan.{plan.data_plane}"
                       f".{plan.control}").inc()
     use_fused = plan.fused
-    use_gram = plan.data_plane == "gram"
     shared = plan.shared_problem
-    has_filter = plan.has_filter
-    has_bias = plan.has_bias
-    ndev = plan.n_devices
 
+    with obtrace.span("engine.make_problem"):
+        problems, pkeys, pid_np, A_np, y_np, w_true = _make_problems(
+            specs, shared)
+    d = A_np.shape[-1]
+    with obtrace.span("engine.stage_problem"):
+        scan_fn, operands = _stage_problem(
+            specs, sched, plan, mesh, problems, pkeys, A_np, y_np, T=T,
+            n_max=n_max, kernel_impl=kernel_impl, stream_dtype=stream_dtype,
+            telemetry=telemetry)
+
+    # -- async chunk pipeline (depth 1; see engineplan.pipeline) ----------
+    with obtrace.span("engine.scan", B=B, T=T,
+                      data_plane=plan.data_plane, control=plan.control):
+        W, losses, det, extras = run_chunks(
+            scan_fn, plan, B=B, T=T, d=d, n_max=n_max, mesh=mesh,
+            A_np=A_np, y_np=y_np, pid_np=pid_np, **operands)
+    tel_counts = extras.pop("telemetry") if telemetry else None
+
+    with obtrace.span("engine.results"):
+        # -- materialize results: control plane + device values ---------------
+        from repro.core.simulation import SimResult
+
+        trace = None
+        if device_mode:
+            # reconstruct the full host control plane from the decision
+            # trace (exact — the streams are counter-indexed, so schedule,
+            # meters and eliminations are pure functions of the trace)
+            trace = dict(q=extras["q"], check=extras["check"],
+                         detect=det.copy(), faulty2=extras["faulty2"])
+            rec = ScheduleRecorder()
+            control = replay_control_from_trace(specs, trace, rec)
+            keys = rec.steps[0].keys() if rec.steps else ()
+            arrays = {k: np.stack([st[k] for st in rec.steps]) for k in keys}
+            sched = Schedule(arrays, control, True, "device")
+
+        results = []
+        for b, (s, ctrl) in enumerate(zip(specs, sched.control.results)):
+            results.append(SimResult(
+                w=W[b],
+                w_true=w_true[b],
+                state=ctrl.state,
+                losses=losses[:s.steps, b].tolist(),
+                q_trace=ctrl.q_trace,
+                identify_step=ctrl.identify_step,
+            ))
+        tel_obj = None
+        if telemetry:
+            tel_obj = Telemetry.from_counts(
+                tel_counts, specs=specs,
+                q_traces=[r.q_trace for r in results])
+            obmetrics.counter("engine.telemetry.steps").inc(
+                tel_obj.totals()["steps"])
+        out = BatchResult(specs, results, time.perf_counter() - t_start,
+                          plan=plan, telemetry=tel_obj)
+
+    out.detect_flags = det
+    out.schedule = sched
+    out.device_trace = trace
+    out.fused_used = use_fused
+    return out
+
+
+def _make_problems(specs: list[TrialSpec], shared: bool):
+    """The call's problems, one draw per distinct (problem_seed, n_data,
+    d), and their f32 host arrays: the one shared problem's, or one per
+    trial.  Returns ``(problems, pkeys, pid_np, A_np, y_np, w_true)``."""
+    B = len(specs)
     # -- real problem arrays (f32 device copies) -------------------------
     problems: dict[tuple, tuple] = {}
     for s in specs:
@@ -361,6 +427,27 @@ def run_batch_jax(specs, *, schedule: str = "auto",
             Ab, yb, wt = problems[(s.problem_seed, s.n_data, s.d)]
             A_np[b], y_np[b] = Ab, yb
             w_true.append(wt)
+    return problems, pkeys, pid_np, A_np, y_np, w_true
+
+
+def _stage_problem(specs: list[TrialSpec], sched, plan, mesh, problems,
+                   pkeys, A_np, y_np, *, T: int, n_max: int,
+                   kernel_impl: str, stream_dtype: str, telemetry: bool):
+    """Host staging of one call up to the scan: the per-trial statics,
+    the scan xs of a host schedule, the data rows and their device
+    precompute, the step core, and the chunk-invariant operands placed
+    on the device.  Returns the step core and the keyword operands of
+    :func:`run_chunks` that this staging decides."""
+    from repro.kernels import ops
+
+    B = len(specs)
+    n_data, d = A_np.shape[-2:]
+    device_mode = plan.control == "device"
+    use_fused = plan.fused
+    use_gram = plan.data_plane == "gram"
+    shared = plan.shared_problem
+    has_filter = plan.has_filter
+    has_bias = plan.has_bias
 
     # -- per-trial statics ------------------------------------------------
     abn = np.array([AFFINE_ATTACKS[s.attack] for s in specs], np.float32)
@@ -554,55 +641,6 @@ def run_batch_jax(specs, *, schedule: str = "auto",
         com_dev = put(common, in_specs[6])
         noise_dev = (None if (use_fused or use_gram) else
                      put(noisevec, in_specs[7]))
-
-    # -- async chunk pipeline (depth 1; see engineplan.pipeline) ----------
-    with obtrace.span("engine.scan", B=B, T=T,
-                      data_plane=plan.data_plane, control=plan.control):
-        W, losses, det, extras = run_chunks(
-            scan_fn, plan, B=B, T=T, d=d, d_run=d_run, n_max=n_max,
-            mesh=mesh, in_specs=in_specs, A_np=A_np, y_np=y_np,
-            A_dev=A_dev, y_dev=y_dev, com_dev=com_dev,
-            noise_dev=noise_dev, pid_np=pid_np, stat_np=stat_np,
-            xs_np=xs_np)
-    tel_counts = extras.pop("telemetry") if telemetry else None
-
-    # -- materialize results: control plane + device values ---------------
-    from repro.core.simulation import SimResult
-
-    trace = None
-    if device_mode:
-        # reconstruct the full host control plane from the decision
-        # trace (exact — the streams are counter-indexed, so schedule,
-        # meters and eliminations are pure functions of the trace)
-        trace = dict(q=extras["q"], check=extras["check"],
-                     detect=det.copy(), faulty2=extras["faulty2"])
-        rec = ScheduleRecorder()
-        control = replay_control_from_trace(specs, trace, rec)
-        keys = rec.steps[0].keys() if rec.steps else ()
-        arrays = {k: np.stack([st[k] for st in rec.steps]) for k in keys}
-        sched = Schedule(arrays, control, True, "device")
-
-    results = []
-    for b, (s, ctrl) in enumerate(zip(specs, sched.control.results)):
-        results.append(SimResult(
-            w=W[b],
-            w_true=w_true[b],
-            state=ctrl.state,
-            losses=losses[:s.steps, b].tolist(),
-            q_trace=ctrl.q_trace,
-            identify_step=ctrl.identify_step,
-        ))
-    tel_obj = None
-    if telemetry:
-        tel_obj = Telemetry.from_counts(
-            tel_counts, specs=specs,
-            q_traces=[r.q_trace for r in results])
-        obmetrics.counter("engine.telemetry.steps").inc(
-            tel_obj.totals()["steps"])
-    out = BatchResult(specs, results, time.perf_counter() - t_start,
-                      plan=plan, telemetry=tel_obj)
-    out.detect_flags = det
-    out.schedule = sched
-    out.device_trace = trace
-    out.fused_used = use_fused
-    return out
+    return scan_fn, dict(d_run=d_run, in_specs=in_specs, A_dev=A_dev,
+                         y_dev=y_dev, com_dev=com_dev, noise_dev=noise_dev,
+                         stat_np=stat_np, xs_np=xs_np)

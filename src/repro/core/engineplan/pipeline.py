@@ -82,8 +82,10 @@ def run_chunks(scan_fn, plan, *, B: int, T: int, d: int, d_run: int,
     def _stage(lo: int):
         """H2D-transfer one chunk's per-trial arrays (async)."""
         hi = min(lo + chunk_trials, B)
-        with obtrace.span("pipeline.stage", lo=lo, hi=hi):
-            return _stage_inner(lo, hi)
+        with obtrace.span("pipeline.stage", lo=lo, hi=hi) as span_args:
+            sl, bs, args, nbytes = _stage_inner(lo, hi)
+            span_args["bytes"] = nbytes
+        return sl, bs, args
 
     def _stage_inner(lo: int, hi: int):
         bs = hi - lo
@@ -100,14 +102,18 @@ def run_chunks(scan_fn, plan, *, B: int, T: int, d: int, d_run: int,
         # every chunk starts from W0 = 0
         cw0 = np.zeros((bs + pad, Ie), np.float32) if coeff else None
         pid_c = None if coeff else pad_rows(pid_np[lo:hi], 0, pad)
+        host = [W0, cw0, stat_c, xs_c, pid_c]
         if coeff or shared:
             A_c, y_c = A_dev, y_dev
         else:
-            A_c = dev(pad_rows(A_np[lo:hi], 0, pad), 0)
-            y_c = dev(pad_rows(y_np[lo:hi], 0, pad), 1)
+            A_h = pad_rows(A_np[lo:hi], 0, pad)
+            y_h = pad_rows(y_np[lo:hi], 0, pad)
+            host += [A_h, y_h]
+            A_c, y_c = dev(A_h, 0), dev(y_h, 1)
         args = (A_c, y_c, dev(W0, 2), dev(cw0, 3), dev(stat_c, 4),
                 dev(xs_c, 5), com_dev, noise_dev, dev(pid_c, 8))
-        return slice(lo, hi), bs, args
+        nbytes = sum(a.nbytes for a in jax.tree.leaves(host))
+        return slice(lo, hi), bs, args, nbytes
 
     W = np.empty((B, d), np.float64)
     losses = np.empty((T, B))
@@ -121,20 +127,32 @@ def run_chunks(scan_fn, plan, *, B: int, T: int, d: int, d_run: int,
 
     def _drain(sl, bs, out):                     # gathers; blocks
         with obtrace.span("pipeline.drain", lo=sl.start, hi=sl.stop):
-            if telemetry:
-                out, telc = out[:-1], out[-1]
-                for k in TEL_KEYS:
-                    tel_acc[k][sl] = np.asarray(telc[k])[:bs]
-            if device_mode:
-                Wc, lc, qc, cc, dc, fc = out
-                q_tr[:, sl] = np.asarray(qc)[:, :bs]
-                check_tr[:, sl] = np.asarray(cc)[:, :bs]
-                faulty2_tr[:, sl] = np.asarray(fc)[:, :bs]
-            else:
-                Wc, lc, dc = out
-            W[sl] = np.asarray(Wc, np.float64)[:bs, :d]
-            losses[:, sl] = np.asarray(lc, np.float64)[:, :bs]
-            det[:, sl] = np.asarray(dc)[:, :bs]
+            # the host blocked until the chunk's outputs exist: the scan,
+            # and before it the end of the chunk's copies to the device,
+            # which _stage only enqueues; the conversions below would
+            # block on the same outputs anyway
+            with obtrace.span("pipeline.wait"):
+                jax.block_until_ready(out)
+            nbytes = sum(x.nbytes for x in jax.tree.leaves(out))
+            with obtrace.span("pipeline.fetch", bytes=nbytes):
+                _fetch(sl, bs, out)
+
+    def _fetch(sl, bs, out):
+        """D2H of one chunk's outputs into the float64 host results."""
+        if telemetry:
+            out, telc = out[:-1], out[-1]
+            for k in TEL_KEYS:
+                tel_acc[k][sl] = np.asarray(telc[k])[:bs]
+        if device_mode:
+            Wc, lc, qc, cc, dc, fc = out
+            q_tr[:, sl] = np.asarray(qc)[:, :bs]
+            check_tr[:, sl] = np.asarray(cc)[:, :bs]
+            faulty2_tr[:, sl] = np.asarray(fc)[:, :bs]
+        else:
+            Wc, lc, dc = out
+        W[sl] = np.asarray(Wc, np.float64)[:bs, :d]
+        losses[:, sl] = np.asarray(lc, np.float64)[:, :bs]
+        det[:, sl] = np.asarray(dc)[:, :bs]
 
     staged = _stage(0)
     inflight = None

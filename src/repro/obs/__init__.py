@@ -7,11 +7,12 @@ docs/observability.md):
   that ``run_batch(..., telemetry=True)`` threads through the scan
   carry (detections, votes, eliminations, tamper events, the paper's
   redundancy-overhead fraction), returned as ``BatchResult.telemetry``;
-* :mod:`repro.obs.trace` — host span tracing (context manager +
-  decorator) with Chrome-trace JSON export and the ``profile_trace``
-  hook that nests ``jax.profiler.trace`` under ``REPRO_PROFILE``;
-* :mod:`repro.obs.metrics` — a process-wide counter/gauge/histogram
-  registry with JSONL export.
+* :mod:`repro.obs.trace` — host span tracing (a context manager whose
+  spans are also profiler annotations) with Chrome-trace JSON export
+  and the ``profile_trace`` hook that nests ``jax.profiler.trace``
+  under ``REPRO_PROFILE``;
+* :mod:`repro.obs.metrics` — a process-wide counter/gauge registry
+  with JSONL export.
 
 :mod:`repro.obs.report` renders a ``BatchResult`` into the paper's
 efficiency accounting (observed redundancy overhead vs the eq-2
@@ -27,4 +28,4 @@ from repro.obs import metrics, oblog, telemetry, trace  # noqa: F401
 from repro.obs.metrics import REGISTRY  # noqa: F401
 from repro.obs.oblog import reset_warn_once, warn_once  # noqa: F401
 from repro.obs.telemetry import TEL_KEYS, Telemetry  # noqa: F401
-from repro.obs.trace import TRACER, profile_trace, span, traced  # noqa: F401
+from repro.obs.trace import TRACER, profile_trace, span  # noqa: F401

@@ -1,4 +1,4 @@
-"""Process-wide metrics registry: counters, gauges, histograms.
+"""Process-wide metrics registry: counters and gauges.
 
 One global :data:`REGISTRY` (module-level helpers delegate to it) with
 JSONL export — each :meth:`MetricsRegistry.export_jsonl` call appends
@@ -48,43 +48,10 @@ class Gauge:
         return {"kind": self.kind, "value": self.value}
 
 
-class Histogram:
-    """Streaming summary of an observed distribution (latencies).
-
-    Keeps count/total/min/max — enough for mean and range without
-    unbounded storage; per-event detail belongs in the span tracer.
-    """
-
-    kind = "histogram"
-
-    def __init__(self, name: str):
-        self.name = name
-        self.count = 0
-        self.total = 0.0
-        self.min = None
-        self.max = None
-
-    def observe(self, v: float) -> None:
-        v = float(v)
-        self.count += 1
-        self.total += v
-        self.min = v if self.min is None else min(self.min, v)
-        self.max = v if self.max is None else max(self.max, v)
-
-    @property
-    def mean(self) -> float:
-        return self.total / self.count if self.count else 0.0
-
-    def snapshot(self) -> dict:
-        return {"kind": self.kind, "count": self.count,
-                "total": self.total, "mean": self.mean,
-                "min": self.min, "max": self.max}
-
-
 class MetricsRegistry:
     """Named metrics, created on first touch, one namespace per process."""
 
-    _KINDS = {"counter": Counter, "gauge": Gauge, "histogram": Histogram}
+    _KINDS = {"counter": Counter, "gauge": Gauge}
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -107,9 +74,6 @@ class MetricsRegistry:
 
     def gauge(self, name: str) -> Gauge:
         return self._get(name, "gauge")
-
-    def histogram(self, name: str) -> Histogram:
-        return self._get(name, "histogram")
 
     def snapshot(self) -> dict:
         """name -> {kind, ...values}, sorted for stable diffs."""
@@ -137,7 +101,6 @@ REGISTRY = MetricsRegistry()
 
 counter = REGISTRY.counter
 gauge = REGISTRY.gauge
-histogram = REGISTRY.histogram
 snapshot = REGISTRY.snapshot
 export_jsonl = REGISTRY.export_jsonl
 reset = REGISTRY.reset

@@ -5,7 +5,10 @@ harness' old private ``_profiled`` helper.
 Spans record into a bounded in-process ring buffer (no I/O on the hot
 path, no background thread); :func:`export_chrome` writes the buffer as
 Chrome-trace JSON ("X" complete events) loadable in ``chrome://tracing``
-/ Perfetto.  ``profile_trace`` additionally nests
+/ Perfetto.  Every span is also a ``jax.profiler.TraceAnnotation`` of
+the same name, so while a JAX profiler trace runs the spans land in its
+host plane on the device trace's clock (about a microsecond a span when
+no profiler runs).  ``profile_trace`` additionally nests
 ``jax.profiler.trace(<dir>/<label>)`` when ``REPRO_PROFILE=<dir>`` is
 set (or an explicit ``profile_dir`` is passed) so kernel/HBM-level
 traces line up with the host spans — the single implementation shared
@@ -22,53 +25,61 @@ import threading
 import time
 
 
+@functools.lru_cache(maxsize=None)
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on the first span so
+    that importing this module pulls in no jax."""
+    import jax
+
+    return jax.profiler.TraceAnnotation
+
+
 class SpanTracer:
-    """Bounded ring buffer of completed spans."""
+    """Bounded ring buffer of completed spans; counts the spans it
+    evicts once full."""
 
     def __init__(self, maxlen: int = 65536):
         self._lock = threading.Lock()
         self._events: collections.deque = collections.deque(maxlen=maxlen)
+        self._dropped = 0
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
-        """Record a wall-clock span around the enclosed block.
+        """Record a wall-clock span around the enclosed block, and a
+        profiler annotation ``name`` around the same block.
 
         Extra keyword arguments land in the event's ``args`` dict
-        (small JSON-serializable values: chunk index, schedule mode)."""
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            dur = time.perf_counter_ns() - t0
-            ev = {"name": name, "ts_ns": t0, "dur_ns": dur,
-                  "tid": threading.get_ident()}
-            if args:
-                ev["args"] = args
-            with self._lock:
-                self._events.append(ev)
-
-    def traced(self, name: str | None = None):
-        """Decorator form of :meth:`span` (span name defaults to the
-        function's qualified name)."""
-        def deco(fn):
-            label = name or fn.__qualname__
-
-            @functools.wraps(fn)
-            def wrapper(*a, **kw):
-                with self.span(label):
-                    return fn(*a, **kw)
-
-            return wrapper
-
-        return deco
+        (small JSON-serializable values: chunk index, schedule mode),
+        never in the annotation.  The dict is yielded, so the block can
+        add what it learns while it runs (bytes moved)."""
+        with _annotation()(name):
+            t0 = time.perf_counter_ns()
+            try:
+                yield args
+            finally:
+                dur = time.perf_counter_ns() - t0
+                ev = {"name": name, "ts_ns": t0, "dur_ns": dur,
+                      "tid": threading.get_ident()}
+                if args:
+                    ev["args"] = args
+                with self._lock:
+                    if len(self._events) == self._events.maxlen:
+                        self._dropped += 1
+                    self._events.append(ev)
 
     def spans(self) -> list[dict]:
         with self._lock:
             return list(self._events)
 
+    def dropped(self) -> int:
+        """Spans evicted from the full buffer since the last clear."""
+        with self._lock:
+            return self._dropped
+
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
+            self._dropped = 0
 
     def export_chrome(self, path: str) -> str:
         """Write the buffered spans as Chrome-trace JSON ("X" events,
@@ -94,8 +105,8 @@ class SpanTracer:
 TRACER = SpanTracer()
 
 span = TRACER.span
-traced = TRACER.traced
 spans = TRACER.spans
+dropped = TRACER.dropped
 clear = TRACER.clear
 export_chrome = TRACER.export_chrome
 
