@@ -31,8 +31,10 @@ Exactness contract: for a ``TrialSpec`` whose fields match
 paths share the numerical primitives below, and every batched matmul
 keeps the per-item operand shapes of the serial path (numpy loops
 leading batch dims, calling the same BLAS routine per item), so the
-floating-point stream is identical for any batch size.
-tests/test_engine_parity.py pins this down.
+floating-point stream is identical for any batch size.  Large batches
+run their products in trial chunks on a host thread pool; each chunk
+issues the same per-item BLAS calls, so that holds there too.
+tests/test_engine_parity.py and tests/test_numpy_pool.py pin this down.
 
 Beyond parity, trials may declare engine-only scenario features:
 ``onset`` (late-onset Byzantine behavior — workers behave honestly
@@ -48,9 +50,12 @@ tests/scenarios.  See docs/scenarios.md for the vocabulary.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
 import itertools
+import os
+import threading
 import time
 from typing import Callable
 
@@ -71,7 +76,7 @@ from repro.core.assignment import (
 )
 from repro.core.identification import majority_vote_np
 from repro.core.randomized import BFTConfig, ProtocolState, decide_generator
-from repro.obs import trace as obtrace
+from repro.obs import metrics, trace as obtrace
 from repro.obs.telemetry import Telemetry, zero_counts
 
 # ---------------------------------------------------------------------------
@@ -83,17 +88,104 @@ from repro.obs.telemetry import Telemetry, zero_counts
 # matter how many trials share the pass.  (Reshaping into one big GEMM
 # would be faster still but changes the accumulation pattern — verified
 # non-identical — so we deliberately stay per-item.)
+#
+# A trial-chunked call (``chunks=``) splits the leading trial axis into
+# slices and runs each slice's np.matmul on a host thread pool: every
+# slice issues exactly the per-item BLAS calls of the unsplit batch, on
+# the same operands, so the results stay bitwise identical too; numpy
+# releases the GIL around BLAS, so the slices run at once.  Each
+# primitive is written once, as the body of one slice: an unchunked
+# call runs it over every trial on the calling thread.
 # ---------------------------------------------------------------------------
+
+# I·d, the elements of one trial's data, from which a product may go to
+# the pool: it keeps the small-d callers (the d = 8 scenario grids, the
+# d = 4 proxy schedule) serial and lets the large ones (I·d in the
+# hundreds of thousands) pool; where between them the handover starts to
+# pay was not measured
+POOL_MIN_ITEM = 1 << 14
+
+_pool_lock = threading.Lock()
+_pool: concurrent.futures.ThreadPoolExecutor | None = None
+
+
+def _forget_pool() -> None:
+    """A forked child has none of its parent's threads: make a new pool."""
+    global _pool, _pool_lock
+    _pool_lock = threading.Lock()
+    _pool = None
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_forget_pool)
+
+
+def pool_threads() -> int:
+    """Host threads the pool may use: the CPUs this process may run on."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:       # no affinity call on this platform
+        return os.cpu_count() or 1
+
+
+def trial_chunks(S: int, item: int) -> list[slice] | None:
+    """The trial slices a product over ``S`` trials of ``item`` (I·d)
+    elements each runs on the pool, or None to run it serially.
+
+    The pool engages only when every thread gets at least two trials,
+    and a trial's data holds at least ``POOL_MIN_ITEM`` elements; it
+    then splits the trials into one slice a thread."""
+    threads = pool_threads()
+    if threads < 2 or S < 2 * threads or item < POOL_MIN_ITEM:
+        return None
+    edges = [S * i // threads for i in range(threads + 1)]
+    return [slice(a, b) for a, b in zip(edges, edges[1:])]
+
+
+def _executor() -> concurrent.futures.ThreadPoolExecutor:
+    """The module's pool, of ``pool_threads()`` threads, made on first
+    use."""
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            threads = pool_threads()
+            _pool = concurrent.futures.ThreadPoolExecutor(
+                threads, thread_name_prefix="engine-numpy")
+            metrics.gauge("engine.numpy.pool_threads").set(threads)
+        return _pool
+
+
+def _map_chunks(fn: Callable[[slice], None],
+                chunks: list[slice] | None) -> None:
+    """Run ``fn`` on every slice on the pool, re-raising a slice's
+    error; with no ``chunks``, once over every trial on this thread."""
+    if chunks is None:
+        fn(slice(None))
+        return
+    metrics.counter("engine.numpy.pooled_products").inc()
+    for _ in _executor().map(fn, chunks):
+        pass
 
 
 def residuals(A_b: np.ndarray, y_b: np.ndarray, W: np.ndarray,
-              out: np.ndarray | None = None) -> np.ndarray:
+              out: np.ndarray | None = None,
+              chunks: list[slice] | None = None) -> np.ndarray:
     """(B, I, d), (B, I), (B, d) -> (B, I) residual A w - y per trial.
 
     ``out``: optional (B, I, 1) scratch buffer (the engine reuses one
-    across steps; the result aliases it)."""
-    prod = np.matmul(A_b, W[:, :, None], out=out)
-    return np.subtract(prod[:, :, 0], y_b, out=prod[:, :, 0])
+    across steps; the result aliases it).  ``chunks``: trial slices to
+    run on the pool (``trial_chunks``), bitwise the same result."""
+    if out is None:
+        out = np.empty(W.shape[:1] + A_b.shape[1:2] + (1,),
+                       np.result_type(A_b, W))
+
+    def run(c: slice) -> None:
+        o = out[c]
+        np.matmul(A_b[c], W[c, :, None], out=o)
+        np.subtract(o[:, :, 0], y_b[c], out=o[:, :, 0])
+
+    _map_chunks(run, chunks)
+    return out[:, :, 0]
 
 
 def losses_of(resid: np.ndarray) -> np.ndarray:
@@ -102,14 +194,29 @@ def losses_of(resid: np.ndarray) -> np.ndarray:
 
 
 def shard_gradients(A_chunks: np.ndarray, resid_chunks: np.ndarray,
-                    rows: int) -> np.ndarray:
+                    rows: int,
+                    chunks: list[slice] | None = None) -> np.ndarray:
     """Least-squares shard gradients, one contraction per (trial, shard).
 
     A_chunks: (B|1, m, rows, d) — the global batch cut into m contiguous
     shards of ``rows`` rows (remainder dropped); resid_chunks:
     (B, m, 1, rows).  Returns (B, m, d): 2/rows * A_s^T resid_s.
+    ``chunks``: trial slices to run on the pool, bitwise the same result.
     """
-    return 2.0 * np.matmul(resid_chunks, A_chunks)[:, :, 0, :] / rows
+    S, m = resid_chunks.shape[:2]
+    out = np.empty((S, m, 1, A_chunks.shape[-1]),
+                   np.result_type(A_chunks, resid_chunks))
+    shared = A_chunks.shape[0] == 1
+
+    def run(c: slice) -> None:
+        o = out[c]
+        np.matmul(resid_chunks[c], A_chunks if shared else A_chunks[c],
+                  out=o)
+        np.multiply(2.0, o, out=o)
+        np.divide(o, rows, out=o)
+
+    _map_chunks(run, chunks)
+    return out[:, :, 0, :]
 
 
 def worker_gradients(shard_g: np.ndarray, shard_of_worker: np.ndarray,
@@ -139,12 +246,38 @@ def _arange(k: int) -> np.ndarray:
     return out
 
 
-def aggregate(weight: np.ndarray, grads: np.ndarray) -> np.ndarray:
+def aggregate(weight: np.ndarray, grads: np.ndarray,
+              chunks: list[slice] | None = None) -> np.ndarray:
     """(B, n) float32 weights x (B, n, d) grads -> (B, d) updates.
 
     Mixed-dtype matmul promotes the weights to float64 internally —
-    verified bitwise-identical to an explicit astype."""
-    return np.matmul(weight[:, None, :], grads)[:, 0, :]
+    verified bitwise-identical to an explicit astype.  ``chunks``: trial
+    slices to run on the pool, bitwise the same result."""
+    out = np.empty((grads.shape[0], 1, grads.shape[2]),
+                   np.result_type(weight, grads))
+
+    def run(c: slice) -> None:
+        np.matmul(weight[c, None, :], grads[c], out=out[c])
+
+    _map_chunks(run, chunks)
+    return out[:, 0, :]
+
+
+def _step(W: np.ndarray, lr: np.ndarray, grad_upd: np.ndarray,
+          live: np.ndarray, chunks: list[slice] | None) -> np.ndarray:
+    """The iterate update: W - lr * grad for live trials, W frozen for
+    the finished ones.  ``chunks``: trial slices to run on the pool,
+    elementwise and so bitwise the same result."""
+    out = np.empty_like(W)
+
+    def run(c: slice) -> None:
+        o = out[c]
+        np.multiply(lr[c, None], grad_upd[c], out=o)
+        np.subtract(W[c], o, out=o)
+        np.copyto(o, W[c], where=~live[c, None])
+
+    _map_chunks(run, chunks)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -815,6 +948,7 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
     # the gradient buffer can stay uninitialized between steps
     grads = np.empty((B, n_max, d))
     resid_buf = np.empty((B, n_data, 1))
+    item = n_data * d            # a trial's data: sizes the pool's rule
 
     live_const = np.ones(B, bool)
 
@@ -858,7 +992,8 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
         # every float64 product over the problem's data runs under a
         # numpy.data span; the control work between them stays outside
         with obtrace.span("numpy.data"):
-            resid = residuals(A_b, y_b, W, out=resid_buf)    # (B, I)
+            resid = residuals(A_b, y_b, W, out=resid_buf,
+                              chunks=trial_chunks(B, item))  # (B, I)
             loss_col = losses_of(resid)                      # (B,)
             losses_mat[:, t] = loss_col
 
@@ -946,7 +1081,8 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
                 else:
                     Ar = A_b[sub, : m * rows].reshape(len(sub), m, rows, d)
                 rr = resid[sub, : m * rows].reshape(len(sub), m, 1, rows)
-                sg = shard_gradients(Ar, rr, rows)               # (S, m, d)
+                sg = shard_gradients(Ar, rr, rows,
+                                     trial_chunks(len(sub), item))  # (S, m, d)
                 if m == n_max and (group_all[sub] >= 0).all():
                     # fast mode, nobody eliminated: worker w owns shard w —
                     # the gather is the identity, skip it
@@ -1097,10 +1233,11 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
                 live, (byz_mask & bstate.active).sum(axis=1), 0)
 
         with obtrace.span("numpy.data"):
-            grad_upd = aggregate(agg_weight, grads)
+            chunks = trial_chunks(B, item)
+            grad_upd = aggregate(agg_weight, grads, chunks)
             for b, v in voted.items():
                 grad_upd[b] = v
-            W = np.where(live[:, None], W - lr[:, None] * grad_upd, W)
+            W = _step(W, lr, grad_upd, live, chunks)
 
     # -- materialize per-trial results ------------------------------------
     results = []
