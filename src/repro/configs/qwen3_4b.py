@@ -1,6 +1,6 @@
 """qwen3-4b [dense] — 36L d_model=2560 32H (GQA kv=8) d_ff=9728 vocab=151936.
 
-qk_norm, GQA, head_dim=128.  [hf:Qwen/Qwen3-8B; hf]
+qk_norm, GQA, head_dim=128.  [hf:Qwen/Qwen3-4B, config.json]
 """
 from repro.configs.base import ModelConfig, register
 

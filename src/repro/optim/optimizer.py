@@ -2,8 +2,10 @@
 
 Optimizer state mirrors the parameter tree's sharding (ZeRO-1: the state
 lives wherever the param shard lives; with FSDP rules the state is fully
-sharded).  Master copies are f32 regardless of param dtype (mixed
-precision).
+sharded).  The moments are f32 whatever the param dtype; there is no
+f32 master copy of the params: each update is computed in f32 from the
+param as stored and rounded back to its dtype (bf16 params keep bf16
+rounding of every step).
 """
 from __future__ import annotations
 

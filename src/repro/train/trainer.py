@@ -16,6 +16,15 @@ Compiled-step caching: step functions are jitted per assignment signature
 (mode, num_shards, replication, rows); signatures change only on
 elimination / crash events (<= f + #crashes times per run).
 
+Spans and counters (``repro.obs``; tree in docs/observability.md): each
+step is a ``train.step`` span holding its global batch (``train.batch``)
+and one span per compiled step it dispatches (``train.fast``,
+``train.check``, ``train.identify``), each of those the worker batches
+sliced and copied to the devices (``train.put``), a step-cache miss with
+its first call (``train.compile``) and the host's wait for the outputs
+it reads (``train.sync``).  Counters: ``train.steps.<kind>``, ``train.tokens``,
+``train.step_cache_misses``, ``train.faults_detected``.
+
 Supported BFT modes: randomized (paper), deterministic (paper §4.1), draco
 (baseline: permanent 2f+1 voting), filter:<name> (gradient-filter
 baselines), none (vanilla parallelized SGD).
@@ -37,6 +46,8 @@ from repro.core.assignment import Assignment, group_members
 from repro.core.randomized import BFTConfig, ProtocolState
 from repro.data import global_batch_for_step, worker_batches
 from repro.models import model as M
+from repro.obs import metrics as obmetrics
+from repro.obs import trace as obtrace
 from repro.optim import OptConfig, init_opt_state, opt_update
 from repro.sharding import PARAM_RULES, set_mesh, tree_specs
 from repro.train.steps import (
@@ -105,10 +116,13 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def _get_step(self, mode: str, assignment: Assignment):
+        """The compiled step for ``assignment``, and whether it was made
+        now (a miss: its first call compiles it)."""
         rows = self.tc.global_batch // assignment.num_shards
         sig = (mode, assignment.num_shards, assignment.replication, rows)
         if sig in self._step_cache:
-            return self._step_cache[sig]
+            return self._step_cache[sig], False
+        obmetrics.counter("train.step_cache_misses").inc()
         if mode == "fast":
             fn = make_fast_step(self.cfg, self.opt, self.mesh, self.sc, self.attack)
         elif mode == "check":
@@ -132,28 +146,53 @@ class Trainer:
             raise ValueError(mode)
         fn = jax.jit(fn, donate_argnums=(0, 1))
         self._step_cache[sig] = fn
-        return fn
+        return fn, True
 
     def _dispatch(self, mode: str, assignment: Assignment, batch) -> dict:
-        wb = worker_batches(batch, assignment)
-        wb = {k: jnp.asarray(v) for k, v in wb.items()}
-        weights = jnp.asarray(assignment.weight)
-        byz = jnp.asarray(self.true_byz & self.state.active)
-        step_fn = self._get_step(mode, assignment)
-        args = (self.params, self.opt_state, wb, weights, byz)
-        if mode == "check":
-            args = args + (jnp.asarray(assignment.group_of_worker),)
-        args = args + (self.key, jnp.asarray(self.state.step, jnp.int32))
-        self.params, self.opt_state, metrics = step_fn(*args)
+        """Run the compiled ``mode`` step on the global ``batch`` and
+        return its metrics, on the device."""
+        with obtrace.span("train.put"):
+            wb = worker_batches(batch, assignment)
+            wb = {k: jnp.asarray(v) for k, v in wb.items()}
+            weights = jnp.asarray(assignment.weight)
+            byz = jnp.asarray(self.true_byz & self.state.active)
+            extra = ((jnp.asarray(assignment.group_of_worker),)
+                     if mode == "check" else ())
+            step = jnp.asarray(self.state.step, jnp.int32)
+        step_fn, fresh = self._get_step(mode, assignment)
+        args = (self.params, self.opt_state, wb, weights, byz) + extra + (
+            self.key, step)
+        if fresh:
+            with obtrace.span("train.compile"):
+                self.params, self.opt_state, metrics = step_fn(*args)
+        else:
+            self.params, self.opt_state, metrics = step_fn(*args)
         return metrics
+
+    def _run(self, mode: str, assignment: Assignment, batch,
+             *keys: str) -> dict:
+        """One compiled step on ``batch``, span ``train.<mode>`` from its
+        worker batches to its metrics ``loss`` and ``keys`` read on the
+        host."""
+        with obtrace.span(f"train.{mode}"):
+            m = self._dispatch(mode, assignment, batch)
+            with obtrace.span("train.sync"):
+                out = jax.device_get({k: m[k] for k in ("loss",) + keys})
+        obmetrics.counter(f"train.steps.{mode}").inc()
+        return out
 
     # ------------------------------------------------------------------
     def train_step(self) -> dict:
+        with obtrace.span("train.step"):
+            return self._train_step()
+
+    def _train_step(self) -> dict:
         st = self.state
-        batch = global_batch_for_step(
-            self.cfg, global_batch=self.tc.global_batch,
-            seq_len=self.tc.seq_len, step=st.step, seed=self.tc.seed,
-        )
+        with obtrace.span("train.batch"):
+            batch = global_batch_for_step(
+                self.cfg, global_batch=self.tc.global_batch,
+                seq_len=self.tc.seq_len, step=st.step, seed=self.tc.seed,
+            )
         record: dict[str, Any] = {"step": st.step}
 
         mode = self.bft.mode
@@ -162,14 +201,15 @@ class Trainer:
                 self.last_loss
             ):
                 a = st.assignment_check()
-                m = self._dispatch("check", a, batch)
+                m = self._run("check", a, batch, "any_fault")
                 checked = True
                 used = a.num_shards
                 computed = a.gradients_computed()
                 identified = False
                 if bool(m["any_fault"]):
+                    obmetrics.counter("train.faults_detected").inc()
                     ai = st.assignment_identify()
-                    mi = self._dispatch("identify", ai, batch)
+                    mi = self._run("identify", ai, batch, "byz")
                     byz = np.asarray(mi["byz"])
                     st.on_identified(np.flatnonzero(byz))
                     self._step_cache.clear()  # assignments changed shape
@@ -177,7 +217,7 @@ class Trainer:
                     computed += ai.gradients_computed()
                     identified = True
                     record["identified"] = np.flatnonzero(byz).tolist()
-                    m = mi
+                    m, a = mi, ai
                 else:
                     st.on_clean_check(np.flatnonzero(a.group_of_worker >= 0))
                 eff = st.meter.record(
@@ -185,7 +225,7 @@ class Trainer:
                 )
             elif mode == "draco":
                 a = st.assignment_identify()
-                m = self._dispatch("identify", a, batch)
+                m = self._run("identify", a, batch, "byz")
                 byz = np.asarray(m["byz"])
                 newly = np.flatnonzero(byz & ~st.identified)
                 if len(newly):
@@ -197,13 +237,16 @@ class Trainer:
                 )
             elif mode == "filter":
                 a = st.assignment_fast()
-                m = self._dispatch("filter", a, batch)
+                m = self._run("filter", a, batch)
                 eff = st.meter.record(a.num_shards, a.gradients_computed())
             else:  # fast path (randomized default / none)
                 a = st.assignment_fast()
-                m = self._dispatch("fast", a, batch)
+                m = self._run("fast", a, batch)
                 eff = st.meter.record(a.num_shards, a.gradients_computed())
 
+        obmetrics.counter("train.tokens").inc(
+            self.tc.global_batch // a.num_shards * a.num_shards
+            * self.tc.seq_len)
         self.last_loss = float(m["loss"])
         record.update(
             loss=self.last_loss,
