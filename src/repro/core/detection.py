@@ -49,6 +49,23 @@ def hash_sign_sketch(flat_g: jnp.ndarray, key_scalar, k: int = DEFAULT_K):
     return signed.reshape(-1, k).sum(axis=0)
 
 
+def leaf_sketch(leaf: jnp.ndarray, key_scalar, k: int = DEFAULT_K):
+    """``hash_sign_sketch(leaf.reshape(-1))``, up to the order of the f32
+    sums: where the last axis is a multiple of ``k``, coordinate
+    ``(row, col)`` has flat index ``row * n + col`` and bucket ``col % k``,
+    so the leaf is summed over its rows as it is laid out, with no f32
+    copy of the flattened leaf."""
+    n = leaf.shape[-1] if leaf.ndim else 1
+    if leaf.ndim < 2 or n % k:
+        return hash_sign_sketch(leaf.reshape(-1), key_scalar, k)
+    g = leaf.reshape(-1, n)
+    row = jax.lax.broadcasted_iota(jnp.uint32, g.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.uint32, g.shape, 1)
+    signs = _hash_signs(row * jnp.uint32(n) + col, jnp.uint32(key_scalar))
+    return (g.astype(jnp.float32) * signs).sum(axis=0).reshape(-1, k).sum(
+        axis=0)
+
+
 def sketch_tree(grad_tree, key_scalar, k: int = DEFAULT_K):
     """Sketch a whole gradient pytree into one (k,) vector.
 
@@ -60,8 +77,8 @@ def sketch_tree(grad_tree, key_scalar, k: int = DEFAULT_K):
     total = jnp.zeros((k,), jnp.float32)
     offset = jnp.uint32(key_scalar)
     for i, leaf in enumerate(leaves):
-        total = total + hash_sign_sketch(
-            leaf.reshape(-1), offset + jnp.uint32(0x9E3779B9) * jnp.uint32(i + 1), k
+        total = total + leaf_sketch(
+            leaf, offset + jnp.uint32(0x9E3779B9) * jnp.uint32(i + 1), k
         )
     return total
 

@@ -116,18 +116,25 @@ def abstract_embedding(cfg):
     return p
 
 
-def embed(params, tokens, cfg):
+def embed(params, tokens, cfg, hook=None):
+    """``hook`` (the training step's gradient taps on ``params``) taps the
+    lookup; a tied table's head use takes the attack's bias term."""
     # gather rows; scale as in gemma-style models is omitted (standard llama)
-    return params["tokens"].astype(_dt(cfg))[tokens]
+    if hook is None:
+        return params["tokens"].astype(_dt(cfg))[tokens]
+    rows = hook.at("tokens").lookup(params["tokens"], tokens,
+                                    bias=not cfg.tie_embeddings)
+    return rows.astype(_dt(cfg))
 
 
-def unembed(params, x, cfg):
-    if cfg.tie_embeddings:
-        w = params["tokens"].T
-    else:
-        w = params["head"]
-    # logits in f32 for a numerically stable loss
-    return jnp.einsum("...d,dv->...v", x.astype(jnp.float32), w.astype(jnp.float32))
+def unembed(params, x, cfg, hook=None):
+    name = "tokens" if cfg.tie_embeddings else "head"
+    w = params[name] if hook is None else hook.at(name)(params[name])
+    # logits in f32 for a numerically stable loss; a tied table (vocab,
+    # embed) is contracted as it is stored, so its gradient comes out in
+    # the table's own layout
+    spec = "...d,vd->...v" if cfg.tie_embeddings else "...d,dv->...v"
+    return jnp.einsum(spec, x.astype(jnp.float32), w.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------

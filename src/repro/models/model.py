@@ -61,7 +61,11 @@ def init(cfg: ModelConfig, key):
     return materialize(abstract_params(cfg), key)
 
 
-def _encode(params, ctx, cfg):
+def _at(hook, name):
+    return None if hook is None else hook.at(name)
+
+
+def _encode(params, ctx, cfg, hook=None):
     """Whisper-style encoder over stub frame embeddings (B, T, D)."""
     from repro.configs.base import LayerGroup, LayerKind
 
@@ -69,18 +73,18 @@ def _encode(params, ctx, cfg):
     positions = jnp.arange(ctx.shape[1])[None]
     x, _, _ = tfm.run_stack(
         params["encoder"], enc_groups, ctx.astype(jnp.dtype(cfg.dtype)), cfg,
-        positions=positions, causal=False,
+        positions=positions, causal=False, hook=_at(hook, "encoder"),
     )
     return rmsnorm(params["encoder_norm"], x, cfg.norm_eps)
 
 
-def _context(params, batch, cfg):
+def _context(params, batch, cfg, hook=None):
     ctx = batch.get("ctx")
     if ctx is None:
         return None
     ctx = ctx.astype(jnp.dtype(cfg.dtype))
     if cfg.is_encoder_decoder:
-        return _encode(params, ctx, cfg)
+        return _encode(params, ctx, cfg, hook)
     return ctx  # vlm: precomputed patch embeddings used directly
 
 
@@ -88,25 +92,30 @@ def _context(params, batch, cfg):
 # training
 # ---------------------------------------------------------------------------
 
-def forward(params, batch, cfg: ModelConfig, collect_kv: bool = False):
+def forward(params, batch, cfg: ModelConfig, collect_kv: bool = False,
+            hook=None):
+    """``hook``: a training step's gradient taps (``repro.train.gradtap``),
+    placed on each layer's parameters, the embedding's uses and the head;
+    None leaves the model as it is."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     groups = layer_groups(cfg)
-    ctx = _context(params, batch, cfg)
-    x = embed(params["embed"], tokens, cfg)
+    ctx = _context(params, batch, cfg, hook)
+    x = embed(params["embed"], tokens, cfg, _at(hook, "embed"))
     x = constrain_here(x, ("batch", "seq", "embed"))
     positions = jnp.arange(S)[None]
     x, kv_all, aux = tfm.run_stack(
         params["decoder"], groups, x, cfg,
         positions=positions, ctx=ctx, causal=True, collect_kv=collect_kv,
+        hook=_at(hook, "decoder"),
     )
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x, cfg)
+    logits = unembed(params["embed"], x, cfg, _at(hook, "embed"))
     logits = constrain_here(logits, ("batch", "seq", "vocab"))
     return logits, kv_all, aux
 
 
-def train_loss(params, batch, cfg: ModelConfig):
+def train_loss(params, batch, cfg: ModelConfig, hook=None):
     """Mean next-token cross-entropy (+ MoE aux).  Returns (loss, metrics).
 
     The CE is computed as logsumexp - <one_hot, logits> (never a gather
@@ -115,7 +124,7 @@ def train_loss(params, batch, cfg: ModelConfig):
     CE forces an all-gather of the logits, which at 128k vocab is the
     difference between 2 GB and >100 GB of per-chip temps.
     """
-    logits, _, aux = forward(params, batch, cfg)
+    logits, _, aux = forward(params, batch, cfg, hook=hook)
     labels = batch["labels"]
     valid = labels >= 0
     labels_safe = jnp.maximum(labels, 0)

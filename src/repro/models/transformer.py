@@ -133,12 +133,26 @@ def run_stack(
     ctx=None,
     causal: bool = True,
     collect_kv: bool = False,
+    hook=None,
 ):
-    """Scan each group; returns (x, kv_per_attn_layer list, aux_sum)."""
+    """Scan each group; returns (x, kv_per_attn_layer list, aux_sum).
+
+    ``hook``: a training step's gradient taps on ``stack_params``, placed
+    on each layer's parameter slice; a group the hook unrolls is applied
+    layer by layer."""
     aux_total = jnp.zeros((), jnp.float32)
     kv_all: list = []
-    for g, gp in zip(groups, stack_params):
-        if g.repeats == 1 or cfg.unroll_layers:
+    for gi, (g, gp) in enumerate(zip(groups, stack_params)):
+        gh = None if hook is None else hook.at(gi)
+        xs = tuple(gp) if gh is None else gh.scan(gp)
+
+        unrolled = (g.repeats == 1 or cfg.unroll_layers
+                    or (gh is not None and gh.unrolls(g.repeats)))
+
+        def layer_params(xs_r, pos):
+            return xs_r[pos] if gh is None else gh.layer(xs_r, pos)
+
+        if unrolled:
             # tail group / unrolled mode: apply layers directly
             def one_layer(kind, p, x):
                 return apply_layer(
@@ -147,8 +161,9 @@ def run_stack(
                 )
 
             for rep in range(g.repeats):
+                xs_r = jax.tree.map(lambda a: a[rep], xs)
                 for pos, kind in enumerate(g.pattern):
-                    p = jax.tree.map(lambda a: a[rep], gp[pos])
+                    p = layer_params(xs_r, pos)
                     fn = (
                         jax.checkpoint(one_layer, static_argnums=(0,))
                         if cfg.remat
@@ -160,12 +175,13 @@ def run_stack(
                         kv_all.append((kv[0][:, None], kv[1][:, None]))
             continue
 
-        def body(carry, xs):
+        def body(carry, xs_r):
             h, aux_c = carry
             ys = []
             for pos, kind in enumerate(g.pattern):
                 h, kv, aux = apply_layer(
-                    kind, xs[pos], h, cfg, positions=positions, ctx=ctx,
+                    kind, layer_params(xs_r, pos), h, cfg,
+                    positions=positions, ctx=ctx,
                     causal=causal, collect_kv=collect_kv,
                 )
                 aux_c = aux_c + aux
@@ -174,7 +190,7 @@ def run_stack(
             return (h, aux_c), tuple(ys)
 
         body_fn = jax.checkpoint(body) if cfg.remat else body
-        (x, aux_total), ys = jax.lax.scan(body_fn, (x, aux_total), tuple(gp))
+        (x, aux_total), ys = jax.lax.scan(body_fn, (x, aux_total), xs)
         # ys: tuple over attn-positions of (k, v) with leading dim R.
         # Layer order within the group is repeat-major: interleave.
         if collect_kv and ys:

@@ -50,6 +50,7 @@ from repro.core.assignment import Assignment, group_members
 from repro.models import model as M
 from repro.optim import OptConfig, opt_update
 from repro.sharding import shard_map, tree_specs
+from repro.train import gradtap
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,6 +101,43 @@ def _per_worker_grad(params, tokens, labels, byz, key, cfg, attack, ctx=None):
     return loss, grads, did_tamper
 
 
+def _reduced_grad(params, tokens, labels, byz, key, w, cfg, attack, waxes,
+                  n: int):
+    """Loss, this worker's (possibly tampered) gradient, and the gradient
+    summed over the workers in f32 with this worker's weight ``w``.
+
+    Over several workers each piece of the gradient is reduced as the
+    backward pass makes it (``gradtap``), so the compiler may run its
+    all-reduce under the backward pass of the layers below; one worker's
+    sum is the gradient itself, reduced after.
+    """
+    if n == 1:
+        loss, grads, _ = _per_worker_grad(params, tokens, labels, byz, key,
+                                          cfg, attack)
+        return loss, grads, jax.tree.map(
+            lambda g: jax.lax.psum(w * g.astype(jnp.float32), waxes), grads)
+    batch = {"tokens": tokens, "labels": labels}
+    do, ka = byzantine.tamper_coin(key, byz, attack.p_tamper)
+    (loss, _), grads, gagg = gradtap.value_and_reduced_grad(
+        lambda p, hook: M.train_loss(p, batch, cfg, hook=hook), params,
+        do=do, key=ka, weight=w, attack=attack.kind, scale=attack.scale,
+        worker_axes=waxes, workers=n)
+    return loss, grads, gagg
+
+
+def grad_reduce_bytes(cfg, params, workers: int) -> tuple[int, int]:
+    """f32 bytes of the gradient that the fast and check steps reduce
+    inside the backward pass, and after it."""
+    if workers == 1:
+        return 0, 4 * sum(int(np.prod(p.shape))
+                          for p in jax.tree.leaves(params))
+    ids = jnp.zeros((1, 1), jnp.int32)
+    return gradtap.reduced_bytes(
+        lambda p, hook: M.train_loss(p, {"tokens": ids, "labels": ids}, cfg,
+                                     hook=hook),
+        params)
+
+
 def _batch_in_specs(worker_axes, with_ctx: bool):
     w = P(worker_axes if len(worker_axes) > 1 else worker_axes[0])
     tok = P(w[0], None, None)
@@ -114,18 +152,15 @@ def make_fast_step(cfg, opt: OptConfig, mesh, sc: StepConfig,
     """jit(fast_step)(params, opt_state, wbatch, weights, byz_mask, key, step)
     -> (params, opt_state, metrics)."""
     waxes = sc.worker_axes
+    n = num_workers(mesh, waxes)
 
     def body(params, tokens, labels, weights, byz_mask, key, step):
         widx = _worker_index(mesh, waxes)
         kw = jax.random.fold_in(jax.random.fold_in(key, step), widx)
-        ctx = tokens_ctx = None
-        loss, grads, _ = _per_worker_grad(
-            params, tokens[0], labels[0], byz_mask[0], kw, cfg, attack
-        )
         w = weights[0]
-        gagg = jax.tree.map(
-            lambda g: jax.lax.psum(w * g.astype(jnp.float32), waxes), grads
-        )
+        loss, _, gagg = _reduced_grad(
+            params, tokens[0], labels[0], byz_mask[0], kw, w, cfg, attack,
+            waxes, n)
         loss_agg = jax.lax.psum(w * loss, waxes)
         return gagg, loss_agg
 
@@ -194,14 +229,16 @@ def make_check_step(cfg, opt: OptConfig, mesh, sc: StepConfig,
     Returns (params, opt_state, metrics{..., any_fault, group_fault}).
     """
     waxes = sc.worker_axes
+    n = num_workers(mesh, waxes)
 
     def body(params, tokens, labels, weights, byz_mask, group_of_worker,
              key, step):
         widx = _worker_index(mesh, waxes)
         kw = jax.random.fold_in(jax.random.fold_in(key, step), widx)
-        loss, grads, _ = _per_worker_grad(
-            params, tokens[0], labels[0], byz_mask[0], kw, cfg, attack
-        )
+        w = weights[0]
+        loss, grads, gagg = _reduced_grad(
+            params, tokens[0], labels[0], byz_mask[0], kw, w, cfg, attack,
+            waxes, n)
         if sc.detection == "sketch":
             group_fault, mismatch = _detect_sketch(
                 grads, key, step, waxes, group_of_worker, num_groups, sc
@@ -210,10 +247,6 @@ def make_check_step(cfg, opt: OptConfig, mesh, sc: StepConfig,
             group_fault, mismatch = _detect_full(
                 grads, waxes, group_of_worker, num_groups, sc
             )
-        w = weights[0]
-        gagg = jax.tree.map(
-            lambda g: jax.lax.psum(w * g.astype(jnp.float32), waxes), grads
-        )
         loss_agg = jax.lax.psum(w * loss, waxes)
         return gagg, loss_agg, group_fault, mismatch
 

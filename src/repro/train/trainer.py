@@ -23,7 +23,10 @@ and one span per compiled step it dispatches (``train.fast``,
 sliced and copied to the devices (``train.put``), a step-cache miss with
 its first call (``train.compile``) and the host's wait for the outputs
 it reads (``train.sync``).  Counters: ``train.steps.<kind>``, ``train.tokens``,
-``train.step_cache_misses``, ``train.faults_detected``.
+``train.step_cache_misses``, ``train.faults_detected``.  Gauges, set when a
+fast or check step is built: ``train.grad_reduce_in_backward_bytes.<kind>``
+and ``train.grad_reduce_after_backward_bytes.<kind>``, the f32 bytes of
+the gradient reduced inside the backward pass and after it.
 
 Supported BFT modes: randomized (paper), deterministic (paper §4.1), draco
 (baseline: permanent 2f+1 voting), filter:<name> (gradient-filter
@@ -53,6 +56,7 @@ from repro.sharding import PARAM_RULES, set_mesh, tree_specs
 from repro.train.steps import (
     AttackConfig,
     StepConfig,
+    grad_reduce_bytes,
     make_check_step,
     make_fast_step,
     make_identify_step,
@@ -144,6 +148,14 @@ class Trainer:
             )
         else:
             raise ValueError(mode)
+        if mode in ("fast", "check"):
+            inside, after = grad_reduce_bytes(
+                self.cfg, self.params,
+                num_workers(self.mesh, self.sc.worker_axes))
+            obmetrics.gauge(
+                f"train.grad_reduce_in_backward_bytes.{mode}").set(inside)
+            obmetrics.gauge(
+                f"train.grad_reduce_after_backward_bytes.{mode}").set(after)
         fn = jax.jit(fn, donate_argnums=(0, 1))
         self._step_cache[sig] = fn
         return fn, True

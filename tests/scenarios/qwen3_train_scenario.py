@@ -95,8 +95,10 @@ def against_reference(tr: Trainer) -> dict:
 
 
 def counts() -> dict:
+    """The trainer's counters (its gauges are checked in
+    tests/test_gradtap.py)."""
     return {k: v["value"] for k, v in metrics.snapshot().items()
-            if k.startswith("train.")}
+            if k.startswith("train.") and v["kind"] == "counter"}
 
 
 def span_counts() -> dict:
