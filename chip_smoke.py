@@ -1,6 +1,6 @@
 """Drive the engine and the BFT trainer once on TPU chips and check them.
 
-    python3 chip_smoke.py              # one chip: engine_fused, engine_gram,
+    python3 chip_smoke.py              # one chip: engine_stream, engine_gram,
                                        # engine_device_control, trainer
     python3 chip_smoke.py --chips 4    # four chips: engine_sharded, trainer_4
 
@@ -90,7 +90,7 @@ def _peak_bytes():
 
 def _plan_fields(plan) -> dict:
     return {k: getattr(plan, k) for k in (
-        "data_plane", "control", "schedule_mode", "fused", "kernel_impl",
+        "data_plane", "control", "schedule_mode", "kernel_impl",
         "sharded", "n_devices", "chunk_trials", "n_trials", "steps")}
 
 
@@ -173,25 +173,28 @@ def _engine_line(phase, res, compile_s, warm_s, checks, **expect) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def fused_specs(trials=256, d=1 << 20, steps=3, seed=0):
-    """``benchmarks.bench_protocol.fused_sweep``'s drift sweep."""
+def drift_specs(trials=256, d=1 << 20, steps=3, seed=0):
+    """A fixed-q drift sweep on one shared problem of 64 rows at
+    production d."""
     return [TrialSpec(byz=(2, 5), attack="drift", q=0.2, steps=steps,
                       seed=seed + s, problem_seed=seed, n_data=64, d=d)
             for s in range(trials)]
 
 
-def engine_fused(*, trials=256, d=1 << 20, steps=3, n_ref=8, seed=0):
-    """The fused megakernel plane vs the numpy engine."""
-    specs = fused_specs(trials, d, steps, seed)
-    res, compile_s, warm_s = _run_engine(specs, fused=True)
+def engine_stream(*, trials=256, d=1 << 20, steps=3, n_ref=8, seed=0):
+    """The stream plane (the (B, d) iterate carried through the scan) on
+    the drift sweep vs the numpy engine."""
+    specs = drift_specs(trials, d, steps, seed)
+    res, compile_s, warm_s = _run_engine(specs, data_plane="stream")
     checks = _against_numpy(res, specs[:n_ref])
-    return _engine_line("engine_fused", res, compile_s, warm_s, checks,
-                        fused=True)
+    return _engine_line("engine_stream", res, compile_s, warm_s, checks,
+                        data_plane="stream")
 
 
 def engine_gram(*, trials=32, d=1 << 20, steps=120, n_ref=2, seed=0):
-    """The auto plan on ``gram_sweep``'s long-T spec (it must resolve to
-    the gram plane) vs the numpy engine."""
+    """The auto plan on a long-T drift sweep at production d (it must
+    resolve to the gram plane) vs the numpy engine; lr = n_data/d keeps
+    gradient descent contractive at this d."""
     specs = [TrialSpec(byz=(2, 5), attack="drift", q=0.2, steps=steps,
                        seed=seed + s, problem_seed=seed, n_data=64, d=d,
                        lr=64.0 / d)
@@ -204,7 +207,7 @@ def engine_gram(*, trials=32, d=1 << 20, steps=120, n_ref=2, seed=0):
 
 def engine_device_control(*, trials=256, d=1 << 16, steps=24, n_ref=16,
                           seed=0):
-    """``adaptive_sweep``'s adaptive-q spec under ``schedule="device"`` vs
+    """An adaptive-q sign_flip sweep under ``schedule="device"`` vs
     the numpy engine on the same counter-RNG streams (``rng="device"``):
     control exact, q*_t at float tolerance."""
     specs = [TrialSpec(byz=(2, 5), attack="sign_flip", q=None, steps=steps,
@@ -230,12 +233,12 @@ def engine_device_control(*, trials=256, d=1 << 16, steps=24, n_ref=16,
 
 
 def engine_sharded(*, trials=256, d=1 << 20, steps=3, seed=0):
-    """Phase 1's batch sharded over every chip (``mesh="auto"``) vs the
-    same batch on one chip in this process: control exact, values at
-    the f32 cross-configuration tolerance."""
-    specs = fused_specs(trials, d, steps, seed)
-    one = run_batch(specs, backend="jax", fused=True, mesh=None)
-    res, compile_s, warm_s = _run_engine(specs, fused=True, mesh="auto")
+    """Phase 1's batch under the auto plan, sharded over every chip
+    (``mesh="auto"``), vs the same batch on one chip in this process:
+    control exact, values at the f32 cross-configuration tolerance."""
+    specs = drift_specs(trials, d, steps, seed)
+    one = run_batch(specs, backend="jax", mesh=None)
+    res, compile_s, warm_s = _run_engine(specs, mesh="auto")
     checks = {
         "devices": res.plan.n_devices,
         "control_exact": all(_control_equal(a, b) and a.q_trace == b.q_trace
@@ -252,8 +255,7 @@ def engine_sharded(*, trials=256, d=1 << 20, steps=3, seed=0):
                         and checks["detect_flags_exact"]
                         and checks["values_close"])
     return _engine_line("engine_sharded", res, compile_s, warm_s, checks,
-                        fused=True, sharded=True,
-                        n_devices=len(jax.devices()))
+                        sharded=True, n_devices=len(jax.devices()))
 
 
 # ---------------------------------------------------------------------------
@@ -399,7 +401,7 @@ def trainer_4(cfg, *, n=4, seq_len=512, max_steps=20, q=0.5, seed=0):
 def _phases(chips: int, seed: int):
     if chips == 1:
         cfg = model_config(TRAINER_LAYERS)
-        return [lambda: engine_fused(seed=seed),
+        return [lambda: engine_stream(seed=seed),
                 lambda: engine_gram(seed=seed),
                 lambda: engine_device_control(seed=seed),
                 lambda: trainer(cfg, seed=seed)]

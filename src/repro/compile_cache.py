@@ -1,8 +1,7 @@
 """JAX's persistent compilation cache, for the entry points.
 
-Called from ``chip_smoke.py``, ``benchmarks/run.py`` and
-``repro.launch.train`` — never when a module is imported, so tests and
-library users keep JAX's own defaults.
+Called from ``chip_smoke.py`` and ``repro.launch.train`` — never when a
+module is imported, so tests and library users keep JAX's own defaults.
 """
 from __future__ import annotations
 

@@ -45,8 +45,8 @@ problems.
 
 ``ScenarioMatrix`` is the declarative front-end: a named grid of
 attacks × modes × fault patterns × seeds that expands to a trial batch;
-``SCENARIOS`` registers the matrices used by benchmarks and
-tests/scenarios.  See docs/scenarios.md for the vocabulary.
+``SCENARIOS`` registers the named matrices the examples and
+tests/scenarios run.  See docs/scenarios.md for the vocabulary.
 """
 from __future__ import annotations
 
@@ -681,9 +681,7 @@ class BatchResult:
     results: list                # list[SimResult]
     elapsed_s: float = 0.0
     # jax backend only: the resolved ExecutionPlan (path selection +
-    # explain()/fallback_reason) — supersedes the ad-hoc ``fused_used``
-    # attribute, which the backend still mirrors for compatibility.
-    # The numpy engine leaves it None.
+    # explain()).  The numpy engine leaves it None.
     plan: "ExecutionPlan | None" = None
     # run_batch(..., telemetry=True) only: per-trial protocol counters
     # (repro.obs.telemetry.Telemetry) — identical across backends.

@@ -38,9 +38,8 @@ layered ``repro.core.engineplan`` package (see docs/architecture.md):
 ``run_batch_jax`` resolves the plan once, prepares host arrays, picks
 the jitted/sharded step core, streams the chunks, and assembles the
 ``BatchResult`` — whose ``plan`` attribute reports (and ``explain()``s)
-every path decision, including why a requested fused run demoted
-(``FusedFallbackWarning`` is emitted instead of the old silent
-fallback).
+every path decision, including why a requested gram run demoted
+(``PlanFallbackWarning``).
 
 Parity contract (tests/test_engine_parity.py, docs/performance.md):
 control quantities — efficiency counters, check/identify schedules,
@@ -79,11 +78,9 @@ from repro.core.engineplan.pipeline import run_chunks
 from repro.core.engineplan.plan import (
     AFFINE_ATTACKS,            # noqa: F401  (public: tests import it here)
     ExecutionPlan,             # noqa: F401  (public re-export)
-    FusedFallbackWarning,      # noqa: F401  (public re-export)
     PlanFallbackWarning,       # noqa: F401  (public re-export)
     device_schedulable,        # noqa: F401  (public re-export)
     resolve_plan,
-    value_independent_control,
 )
 from repro.core.engineplan.shard import shard_wrap
 from repro.core.engineplan.stepcore import (
@@ -95,21 +92,8 @@ from repro.core.simulation import make_problem
 from repro.obs import metrics as obmetrics, trace as obtrace
 from repro.obs.telemetry import Telemetry
 
-_FILTER_CODES = planlib.FILTER_CODES
-
 _PROXY_N_DATA = 64
 _PROXY_D = 4
-
-_filter_name = planlib.filter_name
-_is_adaptive = planlib.is_adaptive
-_validate = planlib.validate_specs
-
-
-def proxy_schedulable(spec: TrialSpec) -> bool:
-    """True when the trial's control flow is value-independent, i.e. the
-    schedule replay may run on a tiny proxy problem — or skip the data
-    plane entirely (engine.replay_control_fast) — at O(1) cost in d."""
-    return value_independent_control(spec)
 
 
 # ---------------------------------------------------------------------------
@@ -175,15 +159,13 @@ def build_schedule(specs: list[TrialSpec], mode: str = "auto") -> Schedule:
 def run_batch_jax(specs, *, schedule: str = "auto",
                   kernel_impl: str | None = None,
                   chunk_trials: int | None = None,
-                  mesh="auto", fused: bool | None = None,
-                  stream_dtype: str = "f32",
-                  data_plane: str | None = None,
+                  mesh="auto", data_plane: str | None = None,
                   telemetry: bool = False) -> BatchResult:
     """Run B protocol trials with the jitted on-device data plane.
 
     schedule: "auto" | "vector" | "proxy" | "oracle" (host control
-        plane; see ``build_schedule``) | "device" (control plane fused
-        into the scan — the only non-oracle option for value-dependent
+        plane; see ``build_schedule``) | "device" (control plane run
+        inside the scan — the only non-oracle option for value-dependent
         classes like adaptive q*_t; requires
         ``engine.device_schedulable`` trials and uses the
         ``rng="device"`` counter-RNG streams, so its parity oracle is
@@ -191,23 +173,6 @@ def run_batch_jax(specs, *, schedule: str = "auto",
         streams).
     kernel_impl: None (auto: Pallas on TPU, XLA elsewhere) | "pallas" |
         "xla" — forwarded to the batched kernel ops.
-    fused: run the data plane through the fused protocol-step
-        megakernel (``ops.fused_step``: update contraction, residual
-        contraction and the per-step detection pre-sketch in ONE HBM
-        pass).  Applies to the shared-problem, non-filter,
-        host-schedule path.  ``None`` (default) auto-enables it
-        whenever eligible; an explicit ``True`` additionally emits a
-        ``FusedFallbackWarning`` if the plan has to demote to the
-        unfused scan (the parity oracle, kept at ``fused=False``).
-        Which path ran — and why — is reported as ``BatchResult.plan``
-        (``plan.fused``, ``plan.fallback_reason``,
-        ``plan.explain()``); the legacy ``BatchResult.fused_used``
-        mirror is kept for compatibility.
-    stream_dtype: "f32" | "bf16" — storage dtype of the streamed data
-        matrix on the fused path (bf16 halves its HBM traffic; all
-        arithmetic and accumulators stay f32, the iterate stays f32).
-        bf16 trades the 1e-4 value-parity contract for bf16-rounded
-        residuals; control quantities are unaffected (host schedule).
     data_plane: None | "gram" | "stream" — the scan's domain.  "gram"
         precomputes the Gram factors once (``ops.gram_factors``: G =
         R R^T, the per-step sketch tables) and scans (B, I) residual
@@ -262,10 +227,9 @@ def run_batch_jax(specs, *, schedule: str = "auto",
     # core, so a mid-process REPRO_KERNEL_IMPL change must not split
     # the run
     kernel_impl = ops.resolve_impl(kernel_impl)
-    # early pure validation (stream dtype, problem dims, attack/filter
-    # tables, schedule-mode eligibility) — resolve_plan re-checks these
-    # for free once the mesh is known
-    planlib.validate_stream_dtype(stream_dtype)
+    # early pure validation (problem dims, attack/filter tables,
+    # schedule-mode eligibility) — resolve_plan re-checks these for free
+    # once the mesh is known
     planlib.validate_specs(specs)
     mode = planlib.resolve_schedule_mode(specs, schedule)
     device_mode = mode == "device"
@@ -287,10 +251,8 @@ def run_batch_jax(specs, *, schedule: str = "auto",
         out = run_batch(specs, telemetry=telemetry)
         out.detect_flags = np.zeros((0, B), bool)
         out.plan = resolve_plan(
-            specs, schedule=schedule, fused=fused,
-            stream_dtype=stream_dtype, kernel_impl=kernel_impl,
+            specs, schedule=schedule, kernel_impl=kernel_impl,
             data_plane=data_plane, telemetry=telemetry)
-        out.fused_used = False
         if device_mode:
             trace = dict(q=np.zeros((0, B), np.float32),
                          check=np.zeros((0, B), bool),
@@ -321,11 +283,10 @@ def run_batch_jax(specs, *, schedule: str = "auto",
     else:
         ndev = None
 
-    # -- resolve the execution plan (pure) and surface fused demotion -----
+    # -- resolve the execution plan (pure) and surface gram demotion ------
     with obtrace.span("engine.resolve_plan", B=B):
-        plan = resolve_plan(specs, schedule=schedule, fused=fused,
+        plan = resolve_plan(specs, schedule=schedule,
                             n_devices=ndev, chunk_trials=chunk_trials,
-                            stream_dtype=stream_dtype,
                             kernel_impl=kernel_impl, n_max=n_max,
                             data_plane=data_plane, telemetry=telemetry)
         planlib.warn_on_fallback(plan)
@@ -333,7 +294,6 @@ def run_batch_jax(specs, *, schedule: str = "auto",
     obmetrics.counter("engine.trials").inc(B)
     obmetrics.counter(f"engine.plan.{plan.data_plane}"
                       f".{plan.control}").inc()
-    use_fused = plan.fused
     shared = plan.shared_problem
 
     with obtrace.span("engine.make_problem"):
@@ -343,8 +303,7 @@ def run_batch_jax(specs, *, schedule: str = "auto",
     with obtrace.span("engine.stage_problem"):
         scan_fn, operands = _stage_problem(
             specs, sched, plan, mesh, problems, pkeys, A_np, y_np, T=T,
-            n_max=n_max, kernel_impl=kernel_impl, stream_dtype=stream_dtype,
-            telemetry=telemetry)
+            n_max=n_max, kernel_impl=kernel_impl, telemetry=telemetry)
 
     # -- async chunk pipeline (depth 1; see engineplan.pipeline) ----------
     with obtrace.span("engine.scan", B=B, T=T,
@@ -394,7 +353,6 @@ def run_batch_jax(specs, *, schedule: str = "auto",
     out.detect_flags = det
     out.schedule = sched
     out.device_trace = trace
-    out.fused_used = use_fused
     return out
 
 
@@ -432,7 +390,7 @@ def _make_problems(specs: list[TrialSpec], shared: bool):
 
 def _stage_problem(specs: list[TrialSpec], sched, plan, mesh, problems,
                    pkeys, A_np, y_np, *, T: int, n_max: int,
-                   kernel_impl: str, stream_dtype: str, telemetry: bool):
+                   kernel_impl: str, telemetry: bool):
     """Host staging of one call up to the scan: the per-trial statics,
     the scan xs of a host schedule, the data rows and their device
     precompute, the step core, and the chunk-invariant operands placed
@@ -443,7 +401,6 @@ def _stage_problem(specs: list[TrialSpec], sched, plan, mesh, problems,
     B = len(specs)
     n_data, d = A_np.shape[-2:]
     device_mode = plan.control == "device"
-    use_fused = plan.fused
     use_gram = plan.data_plane == "gram"
     shared = plan.shared_problem
     has_filter = plan.has_filter
@@ -477,7 +434,7 @@ def _stage_problem(specs: list[TrialSpec], sched, plan, mesh, problems,
             p=np.array([s.p_tamper for s in specs], np.float32),
             qfix=np.array([0.0 if s.q is None else float(s.q)
                            for s in specs], np.float32),
-            qcode=np.array([3 if _is_adaptive(s) else
+            qcode=np.array([3 if planlib.is_adaptive(s) else
                             {"none": 0, "deterministic": 1,
                              "randomized": 2}[s.mode] for s in specs],
                            np.int32),
@@ -488,7 +445,8 @@ def _stage_problem(specs: list[TrialSpec], sched, plan, mesh, problems,
         )
         xs_np = None
     else:
-        fcode = np.array([_FILTER_CODES.get(_filter_name(s), -1)
+        codes = planlib.FILTER_CODES
+        fcode = np.array([codes.get(planlib.filter_name(s), -1)
                           for s in specs], np.int32)
         stat_np = dict(
             base_stat, fcode=fcode,
@@ -528,26 +486,7 @@ def _stage_problem(specs: list[TrialSpec], sched, plan, mesh, problems,
     rows_np[-2] = 1.0
     rows_np[-1] = noisevec
     keys_t = np.uint32(0x9E3779B9) * (np.arange(T, dtype=np.uint32) + 1)
-    d_run = d
-    if use_fused:
-        # the megakernel sketches the rows in-pass, so there is no
-        # hoisted per-step pre-sketch; instead pre-pad the extended
-        # matrix ONCE (block-multiple d, sublane-multiple row count) so
-        # the scan body never pads or slices per step and the kernel's
-        # in-place W aliasing is always eligible.  Zero padding is inert
-        # in all three outputs.
-        from repro.kernels import fused_step as _fs
-
-        Ie = rows_np.shape[0]                      # n_data + 2 (shared)
-        Ie_pad = -(-Ie // 8) * 8
-        d_run = -(-d // _fs.BLOCK_D) * _fs.BLOCK_D
-        rows_f = np.zeros((Ie_pad, d_run), np.float32)
-        rows_f[:Ie, :d] = rows_np
-        rows_dev = jnp.asarray(
-            rows_f,
-            dtype=jnp.bfloat16 if stream_dtype == "bf16" else jnp.float32)
-        common = {"keys": jnp.asarray(keys_t)}
-    elif use_gram:
+    if use_gram:
         # ONE streaming precompute pass replaces both the hoisted
         # per-step pre-sketch AND all in-scan d-traffic: G = R R^T plus
         # every step's sketch table (S0 = W0 R^T is identically zero —
@@ -598,24 +537,20 @@ def _stage_problem(specs: list[TrialSpec], sched, plan, mesh, problems,
     #    chunk-invariant operands ----------------------------------------
     if mesh is None:
         scan_fn = functools.partial(
-            jitted_step_core, fused=use_fused, gram=use_gram,
+            jitted_step_core, gram=use_gram,
             control=plan.control, shared=shared, has_filter=has_filter,
             has_bias=has_bias, impl=kernel_impl,
             telemetry=plan.telemetry)
         # non-shared problems upload per-chunk slices in the pipeline —
         # a full (B, n_data, d) upfront copy would defeat the chunk
-        # memory bound (the fused path reads A only through the
-        # extended rows matrix)
-        if use_fused:
-            A_dev = rows_dev
-        elif use_gram:
+        # memory bound
+        if use_gram:
             A_dev = {"rows": rows_dev, "G": G_dev}
         else:
             A_dev = jnp.asarray(A_np) if shared else None
         y_dev = jnp.asarray(y_np) if shared else None
         com_dev = common
-        noise_dev = (None if (use_fused or use_gram)
-                     else jnp.asarray(noisevec))
+        noise_dev = None if use_gram else jnp.asarray(noisevec)
         in_specs = None
     else:
         stat_sig = tuple((k, v.ndim) for k, v in sorted(stat_np.items()))
@@ -630,17 +565,13 @@ def _stage_problem(specs: list[TrialSpec], sched, plan, mesh, problems,
         ns = lambda spec: NamedSharding(mesh, spec)              # noqa: E731
         put = lambda tree, spec: jax.device_put(                 # noqa: E731
             tree, jax.tree.map(ns, spec))
-        if use_fused:
-            rows_dev = put(rows_dev, in_specs[0])   # replicate once
-            A_dev = rows_dev
-        elif use_gram:
+        if use_gram:
             A_dev = put({"rows": rows_dev, "G": G_dev}, in_specs[0])
         else:
             A_dev = put(A_np, in_specs[0]) if shared else None
         y_dev = put(y_np, in_specs[1]) if shared else None
         com_dev = put(common, in_specs[6])
-        noise_dev = (None if (use_fused or use_gram) else
-                     put(noisevec, in_specs[7]))
-    return scan_fn, dict(d_run=d_run, in_specs=in_specs, A_dev=A_dev,
+        noise_dev = None if use_gram else put(noisevec, in_specs[7])
+    return scan_fn, dict(in_specs=in_specs, A_dev=A_dev,
                          y_dev=y_dev, com_dev=com_dev, noise_dev=noise_dev,
                          stat_np=stat_np, xs_np=xs_np)

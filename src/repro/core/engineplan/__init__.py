@@ -5,8 +5,7 @@ Layer diagram (see docs/architecture.md)::
     plan      resolve_plan(specs, ...) -> ExecutionPlan   (pure, no jax)
       |
     stepcore  step_core(...)  one parameterized lax.scan step
-      |                       (fused / control statics replace the three
-      |                        hand-specialized cores)
+      |                       (control / gram statics pick the planes)
     shard     shard_wrap(plan, mesh, ...)  one shard_map builder
       |
     pipeline  run_chunks(...)  chunked async H2D/donation pipeline
@@ -24,10 +23,9 @@ from repro.core.engineplan.plan import (  # noqa: F401
     AFFINE_ATTACKS,
     CHUNK_ELEMS,
     FILTER_CODES,
-    STREAM_DTYPES,
     VALUE_INDEPENDENT_ATTACKS,
     ExecutionPlan,
-    FusedFallbackWarning,
+    PlanFallbackWarning,
     device_schedulable,
     filter_name,
     is_adaptive,
@@ -36,7 +34,6 @@ from repro.core.engineplan.plan import (  # noqa: F401
     resolve_schedule_mode,
     spec_display_names,
     validate_specs,
-    validate_stream_dtype,
     value_independent_control,
     warn_on_fallback,
 )

@@ -38,29 +38,26 @@ def pad_rows(arr: np.ndarray, axis: int, pad: int, fill=0) -> np.ndarray:
     return np.pad(arr, widths, constant_values=fill)
 
 
-def run_chunks(scan_fn, plan, *, B: int, T: int, d: int, d_run: int,
-               n_max: int, mesh, in_specs, A_np, y_np, A_dev, y_dev,
-               com_dev, noise_dev, pid_np, stat_np, xs_np):
+def run_chunks(scan_fn, plan, *, B: int, T: int, d: int, n_max: int,
+               mesh, in_specs, A_np, y_np, A_dev, y_dev, com_dev,
+               noise_dev, pid_np, stat_np, xs_np):
     """Drive the step core over the batch in plan-sized chunks.
 
     ``A_dev``/``y_dev`` are the pre-placed chunk-invariant operands
-    (the fused path passes its extended rows matrix as ``A_dev``);
+    (the gram plane passes its rows and Gram factors as ``A_dev``);
     non-shared problems upload per-chunk slices of ``A_np``/``y_np``
     instead — a full (B, n_data, d) upfront copy would defeat the chunk
     memory bound.  Returns ``(W, losses, det, extras)`` where
     ``extras`` is ``None`` or a dict holding the device control plane's
     decision trace (q/check/faulty2) and/or the scan's telemetry
     counters under ``"telemetry"``."""
-    fused = plan.fused
-    gram = plan.data_plane == "gram"
-    coeff = fused or gram        # coefficient-plane paths stage cw0
+    gram = plan.data_plane == "gram"      # the gram plane stages cw0
     device_mode = plan.control == "device"
     telemetry = getattr(plan, "telemetry", False)
     shared = plan.shared_problem
     ndev = plan.n_devices
     chunk_trials = plan.chunk_trials
-    Ie = (A_dev["rows"].shape[0] if gram
-          else (A_dev.shape[0] if fused else 0))
+    Ie = A_dev["rows"].shape[0] if gram else 0
 
     if mesh is not None:
         from jax.sharding import NamedSharding
@@ -95,15 +92,13 @@ def run_chunks(scan_fn, plan, *, B: int, T: int, d: int, d_run: int,
         xs_c = None if xs_np is None else {
             k: pad_rows(v[:, lo:hi], 1, pad, PAD_FILL.get(k, 0))
             for k, v in xs_np.items()}
-        W0 = np.zeros((bs + pad, d_run), np.float32)
-        # fused: the pending-coefficient carry starts at zero (no update
-        # to apply on the first kernel call: the pipelined prologue);
+        W0 = np.zeros((bs + pad, d), np.float32)
         # gram: the slot is S0 = W0 @ rows^T, identically zero because
         # every chunk starts from W0 = 0
-        cw0 = np.zeros((bs + pad, Ie), np.float32) if coeff else None
-        pid_c = None if coeff else pad_rows(pid_np[lo:hi], 0, pad)
+        cw0 = np.zeros((bs + pad, Ie), np.float32) if gram else None
+        pid_c = None if gram else pad_rows(pid_np[lo:hi], 0, pad)
         host = [W0, cw0, stat_c, xs_c, pid_c]
-        if coeff or shared:
+        if gram or shared:
             A_c, y_c = A_dev, y_dev
         else:
             A_h = pad_rows(A_np[lo:hi], 0, pad)
@@ -150,7 +145,7 @@ def run_chunks(scan_fn, plan, *, B: int, T: int, d: int, d_run: int,
             faulty2_tr[:, sl] = np.asarray(fc)[:, :bs]
         else:
             Wc, lc, dc = out
-        W[sl] = np.asarray(Wc, np.float64)[:bs, :d]
+        W[sl] = np.asarray(Wc, np.float64)[:bs]
         losses[:, sl] = np.asarray(lc, np.float64)[:, :bs]
         det[:, sl] = np.asarray(dc)[:, :bs]
 

@@ -1,19 +1,17 @@
 """ExecutionPlan: the engine's path selection as an inspectable value.
 
-``run_batch(backend="jax")`` used to pick its execution path — host vs
-on-device control plane, fused megakernel vs unfused scan, sharded vs
-single-device, chunk size — through predicates scattered across
-``run_batch_jax``, with the fused fallback silently demoting.  This
-module resolves all of it ONCE, up front, into a frozen
+``run_batch(backend="jax")`` picks its execution path — host vs
+on-device control plane, gram vs stream data plane, sharded vs
+single-device, chunk size — once, up front, into a frozen
 :class:`ExecutionPlan`:
 
 * :func:`resolve_plan` is pure — specs plus keyword knobs in, plan out —
   so every path decision is unit-testable without touching a device
   (tests/test_execution_plan.py covers the full SCENARIOS grid);
 * :meth:`ExecutionPlan.explain` names which path was picked and *why*,
-  including the reason a requested fused run demoted
-  (:attr:`ExecutionPlan.fallback_reason`, surfaced as a
-  :class:`FusedFallbackWarning` by the engine facade);
+  including the reason a requested gram run demoted
+  (:attr:`ExecutionPlan.data_plane_reason`, surfaced as a
+  :class:`PlanFallbackWarning` by the engine facade);
 * the schedulability predicates (``value_independent_control``,
   ``device_schedulable``) and the affine-attack / filter tables live
   here as the single source of truth — ``repro.core.engine`` and
@@ -54,7 +52,6 @@ VALUE_INDEPENDENT_ATTACKS = frozenset({"none", "drift", "noise"})
 FILTER_CODES = {"mean": 0, "median": 1, "krum": 2}
 
 HOST_SCHEDULE_MODES = ("auto", "vector", "proxy", "oracle")
-STREAM_DTYPES = ("f32", "bf16")
 
 # element budget for sizing trials-per-device-chunk: the scan's largest
 # live array is ~4 (B, d) buffers (W + update terms), or the (B, n, d)
@@ -73,17 +70,8 @@ GRAM_MIN_D_RATIO = 4
 class PlanFallbackWarning(UserWarning):
     """A requested execution path was demoted by the plan's eligibility
     gates; the message (and the matching ``ExecutionPlan`` reason field)
-    says why.  Filter with ``warnings.filterwarnings`` by this category
-    to catch every demotion class (fused, data_plane, ...)."""
-
-
-class FusedFallbackWarning(PlanFallbackWarning):
-    """Deprecated alias kept for the pre-data_plane engine-specific
-    naming: ``fused=True`` demotions are still *emitted* under this
-    subclass, so existing ``warnings.filterwarnings`` /
-    ``pytest.warns(FusedFallbackWarning)`` filters keep matching; new
-    code should catch :class:`PlanFallbackWarning`, which also covers
-    ``data_plane="gram"`` demotions."""
+    says why.  Filter with ``warnings.filterwarnings`` by this
+    category."""
 
 
 # ---------------------------------------------------------------------------
@@ -106,8 +94,8 @@ def is_adaptive(spec) -> bool:
 def value_independent_control(spec) -> bool:
     """True when the trial's control flow (check decisions, detection
     outcomes, identified sets) does not depend on gradient values, i.e.
-    the schedule can be replayed without running the data plane at all.
-    The jax backend's ``proxy_schedulable`` is the same predicate."""
+    the schedule can be replayed without running the data plane at all
+    (the proxy and vector schedules)."""
     if spec.q is None and spec.mode == "randomized":
         return False          # adaptive q*_t depends on the observed loss
     if not spec.byz:
@@ -164,12 +152,6 @@ def nearest_schedule(specs) -> str:
 # ---------------------------------------------------------------------------
 # Validation (shared by resolve_plan and the engine facade)
 # ---------------------------------------------------------------------------
-
-
-def validate_stream_dtype(stream_dtype: str) -> None:
-    if stream_dtype not in STREAM_DTYPES:
-        raise ValueError(f"unknown stream_dtype {stream_dtype!r}; "
-                         f"allowed values: {list(STREAM_DTYPES)}")
 
 
 def validate_specs(specs) -> None:
@@ -253,25 +235,19 @@ def resolve_schedule_mode(specs, mode: str, *, host_only: bool = False) -> str:
 
 @dataclasses.dataclass(frozen=True)
 class ExecutionPlan:
-    """Every path decision of one jax-backend batch, resolved up front.
-
-    Supersedes the ad-hoc ``BatchResult.fused_used`` flag (kept as a
-    plain mirror attribute for compatibility): ``result.plan`` carries
-    the whole picture and ``result.plan.explain()`` says why."""
+    """Every path decision of one jax-backend batch, resolved up front:
+    ``result.plan`` carries the whole picture and
+    ``result.plan.explain()`` says why."""
 
     backend: str                 # "jax"
     schedule_mode: str           # "vector" | "proxy" | "oracle" | "device"
     control: str                 # "host" | "device"
-    fused: bool                  # megakernel data plane actually used
-    fused_requested: bool | None  # True/False explicit; None = auto
-    fallback_reason: str | None  # set whenever fused could not engage
     shared_problem: bool         # one (problem_seed, n_data, d) for all
     has_filter: bool             # gradient-filter baselines in the batch
     has_bias: bool               # some attack has nonzero beta/nu terms
     sharded: bool                # shard_map over the ("trials",) mesh
     n_devices: int               # mesh size (1 when unsharded)
     chunk_trials: int            # trials per device pass (mesh-rounded)
-    stream_dtype: str            # "f32" | "bf16" (fused rows storage)
     kernel_impl: str | None      # resolved batched-kernel dispatch
     n_trials: int                # batch size B
     steps: int                   # scan length T (max steps over specs)
@@ -289,18 +265,9 @@ class ExecutionPlan:
                      "for \"vector\")",
             "oracle": "value-dependent trials present -> real-problem "
                       "host replay",
-            "device": "control plane fused into the jitted scan "
+            "device": "control plane inside the jitted scan "
                       "(rng=\"device\" counter streams)",
         }[self.schedule_mode]
-        if self.fused:
-            fused_line = ("ON — shared problem, no filter baselines, "
-                          "host schedule")
-        elif self.fused_requested is False:
-            fused_line = "OFF — disabled by fused=False"
-        else:
-            req = ("requested but demoted"
-                   if self.fused_requested else "auto-off")
-            fused_line = f"OFF ({req}) — {self.fallback_reason}"
         if self.data_plane == "gram":
             data_line = f"gram — {self.data_plane_reason}"
         elif self.data_plane_reason:
@@ -318,21 +285,17 @@ class ExecutionPlan:
             f"  schedule : {self.schedule_mode} ({self.control} control "
             f"plane) — {sched_why}",
             f"  data     : {data_line}",
-            f"  fused    : {fused_line}",
             f"  sharding : {shard_line}, chunk={self.chunk_trials} "
             f"trials/pass",
             f"  kernels  : impl={self.kernel_impl}, "
-            f"stream_dtype={self.stream_dtype}, "
             f"bias_terms={'yes' if self.has_bias else 'no'}, "
             f"filters={'yes' if self.has_filter else 'no'}",
         ])
 
 
 def resolve_plan(specs, *, schedule: str = "auto",
-                 fused: bool | None = None,
                  n_devices: int | None = None,
                  chunk_trials: int | None = None,
-                 stream_dtype: str = "f32",
                  kernel_impl: str | None = None,
                  n_max: int | None = None,
                  data_plane: str | None = None,
@@ -341,9 +304,6 @@ def resolve_plan(specs, *, schedule: str = "auto",
     :class:`ExecutionPlan` out — no devices touched, so path selection
     is unit-testable for every spec class.
 
-    ``fused``: None = auto (use the megakernel whenever eligible; no
-    warning on demotion), True = explicit request (the facade warns
-    with :class:`FusedFallbackWarning` when demoted), False = off.
     ``n_devices``: mesh size, or None for the single-device jit path.
     ``n_max``: worker-axis width used for filter-chunk sizing; defaults
     to ``max(s.n)``.
@@ -351,9 +311,7 @@ def resolve_plan(specs, *, schedule: str = "auto",
     enough to pay for the precompute), "gram" = explicit request (size
     and control-plane auto-gates waived; hard eligibility still applies
     and demotion warns with :class:`PlanFallbackWarning`), "stream" =
-    the classic (B, d)-carry scan.  ``data_plane="gram"`` conflicts
-    with ``fused=True`` — the megakernel IS the stream plane's fast
-    path and the gram plane replaces the stream entirely.
+    the classic (B, d)-carry scan.
     """
     specs = list(specs)
     if not specs:
@@ -362,13 +320,6 @@ def resolve_plan(specs, *, schedule: str = "auto",
         raise ValueError(
             f"unknown data_plane {data_plane!r}; allowed values: "
             f"'gram', 'stream' (or None for the auto choice)")
-    if data_plane == "gram" and fused is True:
-        raise ValueError(
-            'data_plane="gram" conflicts with fused=True: the fused '
-            "megakernel is the stream plane's fast path and the gram "
-            "plane replaces the stream scan entirely — request one or "
-            "the other")
-    validate_stream_dtype(stream_dtype)
     validate_specs(specs)
     mode = resolve_schedule_mode(specs, schedule)
     control = "device" if mode == "device" else "host"
@@ -392,9 +343,8 @@ def resolve_plan(specs, *, schedule: str = "auto",
     # only, no gradient-filter baselines.  Auto additionally requires
     # host control (the device plane's q*/check coins read the loss,
     # and the gram-domain loss rounds differently in f32 — explicit
-    # data_plane="gram" accepts that documented sliver), an unset
-    # ``fused`` knob (an explicit fused choice pins the stream plane),
-    # and d large enough to amortize the precompute.
+    # data_plane="gram" accepts that documented sliver) and d large
+    # enough to amortize the precompute.
     Ie = specs[0].n_data + 2
     auto_plane = data_plane is None
     use_gram = False
@@ -414,10 +364,6 @@ def resolve_plan(specs, *, schedule: str = "auto",
             f"filter baseline trials ({spec_display_names(specs, flags)}) "
             f"materialize the (B, n, d) gradient stack every step — "
             f"there is no coefficient-only form")
-    elif auto_plane and fused is not None:
-        gram_reason = (
-            f"explicit fused={fused} pins the stream data plane (the "
-            f"fused megakernel and its unfused parity oracle)")
     elif auto_plane and control == "device":
         gram_reason = (
             'auto keeps the stream plane under schedule="device": the '
@@ -437,42 +383,6 @@ def resolve_plan(specs, *, schedule: str = "auto",
             f"coefficients; d={d} is touched once before the scan "
             f"(gram precompute) and once after (W_T contraction)")
 
-    # fused scope gate: shared-problem, non-filter, host-schedule — the
-    # production-d hot path.  Everything else takes the unfused scan
-    # (which doubles as the fused path's parity oracle at fused=False),
-    # and the reason is recorded instead of silently dropped.
-    fallback_reason = None
-    use_fused = False
-    if fused is not False:
-        if use_gram:
-            fallback_reason = (
-                "superseded by the gram data plane: the scan runs in "
-                "coefficient space (resid = S0 - C_t G), so there is no "
-                "d-sized stream left to fuse")
-        elif steps == 0:
-            fallback_reason = ("all trials have steps == 0: nothing to "
-                               "scan")
-        elif control == "device":
-            fallback_reason = (
-                'schedule="device" fuses the control plane into the '
-                "scan; the fused megakernel covers host-schedule runs "
-                "only")
-        elif not shared:
-            n_prob = len({(s.problem_seed, s.n_data, s.d) for s in specs})
-            fallback_reason = (
-                f"trials span {n_prob} distinct problems; the fused "
-                f"megakernel streams ONE shared extended data matrix")
-        elif has_filter:
-            flags = [FILTER_CODES.get(filter_name(s), -1) >= 0
-                     for s in specs]
-            fallback_reason = (
-                f"filter baseline trials "
-                f"({spec_display_names(specs, flags)}) materialize the "
-                f"(B, n, d) gradient stack, which only the unfused scan "
-                f"compiles")
-        else:
-            use_fused = True
-
     # chunk sizing: bound scan memory; only filter trials ever
     # materialize a (chunk, n, d) gradient stack
     ndev = n_devices if n_devices is not None else 1
@@ -489,12 +399,10 @@ def resolve_plan(specs, *, schedule: str = "auto",
 
     return ExecutionPlan(
         backend="jax", schedule_mode=mode, control=control,
-        fused=use_fused, fused_requested=fused,
-        fallback_reason=fallback_reason, shared_problem=shared,
-        has_filter=has_filter, has_bias=has_bias,
+        shared_problem=shared, has_filter=has_filter, has_bias=has_bias,
         sharded=n_devices is not None, n_devices=ndev,
-        chunk_trials=chunk, stream_dtype=stream_dtype,
-        kernel_impl=kernel_impl, n_trials=B, steps=steps,
+        chunk_trials=chunk, kernel_impl=kernel_impl, n_trials=B,
+        steps=steps,
         data_plane="gram" if use_gram else "stream",
         data_plane_requested=data_plane, data_plane_reason=gram_reason,
         telemetry=telemetry,
@@ -503,10 +411,8 @@ def resolve_plan(specs, *, schedule: str = "auto",
 
 def warn_on_fallback(plan: ExecutionPlan, stacklevel: int = 3) -> None:
     """Emit a :class:`PlanFallbackWarning` when an explicitly requested
-    path was demoted (the PR-7 debugging dead-end: the fallback used to
-    be silent).  Fused demotions come out as the
-    :class:`FusedFallbackWarning` subclass for back-compat filters.
-    Zero-step batches never warn — there is no scan at all.
+    gram data plane was demoted to the stream scan.  Zero-step batches
+    never warn — there is no scan at all.
 
     Routed through :func:`repro.obs.oblog.warn_once`: one warning per
     distinct fallback reason per process (a sweep used to repeat it on
@@ -520,12 +426,4 @@ def warn_on_fallback(plan: ExecutionPlan, stacklevel: int = 3) -> None:
             f"(see BatchResult.plan.explain())",
             PlanFallbackWarning,
             key=("gram_fallback", plan.data_plane_reason),
-            stacklevel=stacklevel)
-    if plan.fused_requested is True and not plan.fused and plan.steps > 0:
-        oblog.warn_once(
-            f"fused=True requested but the plan fell back to the "
-            f"unfused scan: {plan.fallback_reason} "
-            f"(see BatchResult.plan.explain())",
-            FusedFallbackWarning,
-            key=("fused_fallback", plan.fallback_reason),
             stacklevel=stacklevel)

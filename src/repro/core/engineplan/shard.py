@@ -1,5 +1,4 @@
-"""Multi-device wrapping of the step core: one ``shard_wrap`` replacing
-the three ``_sharded_*`` builders.
+"""Multi-device wrapping of the step core: one ``shard_wrap`` builder.
 
 Trials are embarrassingly parallel — the scan body touches one trial's
 row everywhere — so the data plane scales out with shard_map over a 1-D
@@ -11,8 +10,8 @@ so the TPU kernel path needs no sharding rules of its own.
 Because :func:`repro.core.engineplan.stepcore.step_core` has ONE
 argument layout for every path (unused slots are ``None`` — an empty
 pytree, so its in_spec is ``None`` too), the wrapper builds one
-in_specs tuple instead of three, and only the out_specs depend on the
-control mode (the device control plane returns its decision trace).
+in_specs tuple, and only the out_specs depend on the control mode (the
+device control plane returns its decision trace).
 """
 from __future__ import annotations
 
@@ -25,7 +24,7 @@ from repro.obs.telemetry import TEL_KEYS
 
 
 @functools.lru_cache(maxsize=32)
-def _build(mesh, fused: bool, gram: bool, control: str, shared: bool,
+def _build(mesh, gram: bool, control: str, shared: bool,
            has_filter: bool, has_bias: bool, impl: str | None,
            stat_sig: tuple, xs_sig: tuple | None, com_sig: tuple,
            a_ndim: int, telemetry: bool = False):
@@ -38,15 +37,12 @@ def _build(mesh, fused: bool, gram: bool, control: str, shared: bool,
     cache) instead of recompiling."""
     from repro.sharding import shard_map, trial_partition_spec as ts
 
-    coeff = fused or gram        # coefficient-plane carry: cw0 shards
     if gram:
-        # the gram factors replicate like the fused rows matrix: every
-        # device scans its trial shard against the same (Ie, Ie) G and
-        # contracts against the same (Ie, d) rows after the scan
+        # the gram factors replicate: every device scans its trial shard
+        # against the same (Ie, Ie) G and contracts against the same
+        # (Ie, d) rows after the scan
         a_spec = {"rows": ts(2, None), "G": ts(2, None)}
         y_spec = ts(1, None)
-    elif fused:
-        a_spec, y_spec = ts(2, None), ts(1, None)
     else:
         # A: the shared data matrix replicates; per-trial stacks shard
         a_spec = ts(a_ndim, None if shared else 0)
@@ -55,13 +51,13 @@ def _build(mesh, fused: bool, gram: bool, control: str, shared: bool,
         a_spec,
         y_spec,
         ts(2, 0),                                          # W0
-        ts(2, 0) if coeff else None,                       # cw0
+        ts(2, 0) if gram else None,                        # cw0
         {k: ts(nd, 0) for k, nd in stat_sig},              # stat
         None if xs_sig is None else
         {k: ts(nd, 1) for k, nd in xs_sig},                # xs (T, B, ..)
         {k: ts(nd, None) for k, nd in com_sig},            # replicated
-        None if coeff else ts(1, None),                    # noisevec
-        None if coeff else ts(1, 0),                       # pid
+        None if gram else ts(1, None),                     # noisevec
+        None if gram else ts(1, 0),                        # pid
     )
     if control == "device":
         # (W, losses, q, check, det, faulty2): the carry's protocol
@@ -74,7 +70,7 @@ def _build(mesh, fused: bool, gram: bool, control: str, shared: bool,
         # the (B,) counters accumulate inside each device's trial shard
         # and stay sharded on the way out — no collective anywhere
         out_specs = out_specs + ({k: ts(1, 0) for k in TEL_KEYS},)
-    body = functools.partial(step_core, fused=fused, gram=gram,
+    body = functools.partial(step_core, gram=gram,
                              control=control, shared=shared,
                              has_filter=has_filter, has_bias=has_bias,
                              impl=impl, telemetry=telemetry)
@@ -90,7 +86,7 @@ def shard_wrap(plan, mesh, *, stat_sig: tuple, xs_sig: tuple | None,
     Returns ``(fn, in_specs)`` — ``in_specs`` doubles as the
     device_put target layout for the chunk pipeline.  Only the plan's
     path statics key the cache; see :func:`_build`."""
-    return _build(mesh, plan.fused, plan.data_plane == "gram",
+    return _build(mesh, plan.data_plane == "gram",
                   plan.control, plan.shared_problem,
                   plan.has_filter, plan.has_bias, plan.kernel_impl,
                   stat_sig, xs_sig, com_sig, a_ndim,

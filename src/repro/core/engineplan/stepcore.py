@@ -1,17 +1,9 @@
 """One parameterized scan step for every engine data-plane path.
 
-The engine used to carry three hand-specialized scan cores —
-``_scan_core`` (host schedule, unfused), ``_fused_scan_core`` (host
-schedule through the protocol-step megakernel) and ``_device_ctl_core``
-(control plane fused into the scan) — each duplicating the
-``contract`` / ``agg`` / ``symbols`` / ``vote_part`` closures.
-:func:`step_core` subsumes all three: ``fused: bool`` and
-``control: "host" | "device"`` are jit-static *configuration*, the
-shared step-epilogue closures are built once, and each static
-configuration traces to exactly the arithmetic of the core it
-replaces — which is what keeps the golden control traces, the
-differential suite and the parity tests bit-identical across the
-refactor.
+:func:`step_core` is the protocol loop for both control planes and both
+data planes: ``control: "host" | "device"`` and ``gram: bool`` are
+jit-static *configuration*, and the shared step-epilogue closures
+(``contract`` / ``agg`` / ``symbols`` / ``vote_part``) are built once.
 
 The ``gram: bool`` static selects the gram-domain data plane on top of
 either control plane: the scan carry is residual *coefficients* only
@@ -19,26 +11,29 @@ either control plane: the scan carry is residual *coefficients* only
 the precomputed Gram factors as ``S_0 - C_t @ G`` (``ops.gram_factors``),
 and ``d`` is touched exactly once after the scan — the post-scan
 contraction materializing ``W_T``.  Per-step cost is O(B·I²) with no
-(B, d) traffic at all.
+(B, d) traffic at all.  The stream plane (``gram=False``) carries the
+(B, d) iterate and pays two d-sized contractions a step.
 
 Unified signature (unused slots are ``None``, an empty pytree under
 jit/shard_map, so one argument layout serves every path)::
 
     step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
-              fused, control, shared, has_filter, has_bias, impl,
+              control, shared, has_filter, has_bias, impl,
               gram=False)
 
 =====  ======================  =========================================
-slot   host unfused            fused / device / gram
+slot   stream                  gram
 =====  ======================  =========================================
-A      (n_data, d) or          fused: (Ie_pad, d_pad) extended rows
-       (B, n_data, d) matrix   gram: {"rows": (Ie, d), "G": (Ie, Ie)}
-                               device: as host unfused
-cw0    None                    fused: (B, Ie_pad) pending-coeff carry
-                               gram: (B, Ie) starting symbols S_0
-xs     (T, B, ...) schedule    device: None (decisions made in-scan)
-com    per-step replicated     fused: {"keys"}; gram: per-step sketch
-                               tables; device: adds "tix"
+A      (n_data, d) or          {"rows": (Ie, d), "G": (Ie, Ie)}
+       (B, n_data, d) matrix
+cw0    None                    (B, Ie) starting symbols S_0
+xs     (T, B, ...) host        as stream
+       schedule; None under
+       device control
+com    per-step sketch tables  per-step sketch tables of the rows;
+       of the data rows;       device control adds "tix"
+       device control adds
+       "tix"
 =====  ======================  =========================================
 
 Outputs: host control -> ``(W, losses, det)``; device control ->
@@ -139,19 +134,18 @@ def masked_mean(g, act):
 
 
 def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
-              fused: bool, control: str, shared: bool, has_filter: bool,
+              control: str, shared: bool, has_filter: bool,
               has_bias: bool, impl: str | None, gram: bool = False,
               telemetry: bool = False):
-    """The protocol loop: scan the schedule (or the fused-in control
+    """The protocol loop: scan the schedule (or the in-scan control
     plane) over iterations, configured by jit-static flags.
 
-    Every iteration pays only two d-sized contractions (one on the
-    fused path: the megakernel folds the pending update, the residual
-    and the per-step detection pre-sketch into ONE HBM pass).  Honest
-    replicas are copies and attacks are affine, so the whole "shard
-    grads → tamper → aggregate/vote" pipeline folds into per-row
-    residual coefficients; detection symbols and vote agreement run in
-    the k-dim sketch domain by the same linearity.  A replica group's
+    Every iteration pays only two d-sized contractions on the stream
+    plane and none on the gram plane.  Honest replicas are copies and
+    attacks are affine, so the whole "shard grads → tamper →
+    aggregate/vote" pipeline folds into per-row residual coefficients;
+    detection symbols and vote agreement run in the k-dim sketch domain
+    by the same linearity.  A replica group's
     symbols are bitwise equal exactly when its full gradients are, so
     symbol-domain winners match the numpy engine's full-vector vote
     outside the detectability floor.  Nothing of shape (B, n, d) is
@@ -167,19 +161,14 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
     n_data = y.shape[-1]
     B = W0.shape[0]
     lr, alpha, beta, nu = stat["lr"], stat["alpha"], stat["beta"], stat["nu"]
-    # "coefficient plane": the fused and gram paths both carry per-row
-    # residual coefficients instead of (B, d) update values, so they
-    # share the tuple-valued agg/vote epilogue below
-    coeff = fused or gram
+    # the gram plane carries per-row residual coefficients instead of
+    # (B, d) update values, through the tuple-valued agg/vote epilogue
     if gram:
         Ie = A["rows"].shape[0]
         Gn = A["G"][:, :n_data]          # symbol columns the scan reads
         S0n = cw0[:, :n_data]
-    else:
-        Ie = A.shape[0] if fused else 0  # extended-rows count
 
-    # ---- shared step epilogue: the closures the three old cores
-    # duplicated, built once and parameterized by the statics ------------
+    # ---- shared step epilogue, parameterized by the statics -------------
 
     def contract(cr):                  # (B, I) row weights -> (B, d)
         if shared:
@@ -189,16 +178,14 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
     def agg(agg_coeff, tam, mask, cr_base):
         """(B, n) aggregation coefficients -> the update, with the
         affine attacks folded in: sum_w coeff_w * attack_w(g_w).
-        Host/device control returns the (B, d) update value; the
-        coefficient plane (fused or gram) returns the residual-
-        coefficient row (B, I) plus its two bias coefficients (the
-        ones-row / noise-row columns of the extended contraction) for
-        the next contraction — the fused kernel's, or the gram carry's
-        — to apply."""
+        The stream plane returns the (B, d) update value; the gram plane
+        returns the residual-coefficient row (B, I) plus its two bias
+        coefficients (the ones-row / noise-row columns of the extended
+        contraction) for the gram carry to apply."""
         aeff = jnp.where(tam, alpha[:, None], 1.0) * agg_coeff
         row = jnp.einsum("bw,bwi->bi", aeff, mask,
                          precision=HIGHEST) * cr_base
-        if coeff:
+        if gram:
             tw = agg_coeff * tam
             return row, (tw * beta[:, None]).sum(axis=1), \
                 (tw * nu[:, None]).sum(axis=1)
@@ -213,15 +200,15 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
         """Per-worker detection symbols: sketch linearity turns the
         worker's gradient sketch into its coefficient row times the
         pre-sketched data rows; attacks act affinely on symbols too.
-        ``SA_b`` is (I, k) on the coefficient plane (the megakernel's
-        in-pass sketch / the gram precompute's per-step table) and
-        (B, I, k) otherwise (per-problem tables gathered by ``pid``)."""
+        ``SA_b`` is (I, k) on the gram plane (the gram precompute's
+        per-step table) and (B, I, k) on the stream plane (per-problem
+        tables gathered by ``pid``)."""
         C = mask * cr_base[:, None, :]                       # (B, n, I)
-        if coeff:
+        if gram:
             skw = jnp.einsum("bwi,ik->bwk", C, SA_b, precision=HIGHEST)
         else:
             skw = jnp.einsum("bwi,bik->bwk", C, SA_b, precision=HIGHEST)
-        if coeff or has_bias:
+        if gram or has_bias:
             add = beta[:, None, None] * sk_one[None, None] \
                 + nu[:, None, None] * sk_noise[None, None]
         else:
@@ -230,20 +217,20 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
                          alpha[:, None, None] * skw + add, skw)
 
     def acc(u, v):                     # update accumulation, either plane
-        if coeff:
+        if gram:
             return (u[0] + v[0], u[1] + v[1], u[2] + v[2])
         return u + v
 
     def upd_zeros():                   # the additive identity of acc()
-        if coeff:
+        if gram:
             return (jnp.zeros((B, n_data)), jnp.zeros(B), jnp.zeros(B))
         return jnp.zeros_like(W0)
 
     def fold_coeff(upd, live):
-        """Coefficient-plane epilogue: (row, b1, b2) -> the (B, Ie)
-        pending-coefficient increment with lr and the live mask folded
-        in (a dead trial's row is exactly zero, so its iterate — fused
-        in-place or gram post-scan — stays bitwise intact)."""
+        """Gram-plane epilogue: (row, b1, b2) -> the (B, Ie)
+        coefficient increment with lr and the live mask folded in (a
+        dead trial's row is exactly zero, so its post-scan iterate
+        stays bitwise intact)."""
         row_u, b1, b2 = upd
         scale = jnp.where(live, lr, 0.0)
         return jnp.concatenate(
@@ -430,16 +417,7 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
     def host_step(carry, xc):
         if telemetry:
             carry, tel = carry
-        if fused:
-            W, cw = carry
-            x, key_t = xc
-            # ONE HBM pass: apply cw_{t-1}, get resid_t and the sketch
-            # table (the pipelined prologue — see docs/performance.md)
-            W, resid_e, sk = ops.fused_step(A, W, cw, key_t, impl=impl)
-            resid = resid_e[:, :n_data] - y[None, :]
-            SA_b = sk[:n_data]
-            sk_one, sk_noise = sk[n_data], sk[n_data + 1]
-        elif gram:
+        if gram:
             # NO d-sized pass at all: symbols of W_t = W0 - C_t @ rows
             # come from the precomputed Gram factors, O(B·I²)
             W = carry                                        # C_t (B, Ie)
@@ -520,7 +498,7 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
         upd = acc(upd, upd2)
 
         # -- gradient-filter baselines (genuinely need the stack;
-        #    the plan gate keeps them off the fused path) --------------
+        #    the plan gate keeps them on the stream plane) -------------
         if has_filter:
             C = mask1 * cr1[:, None, :]
             if shared:
@@ -537,9 +515,7 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
                              masked_krum(gt1, act, farr), fupd)
             upd = jnp.where((fcode >= 0)[:, None], fupd, upd)
 
-        if fused:
-            new_carry = (W, fold_coeff(upd, x["live"]))
-        elif gram:
+        if gram:
             new_carry = W + fold_coeff(upd, x["live"])
         else:
             new_carry = jnp.where(x["live"][:, None],
@@ -570,27 +546,14 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
             return (new_carry, tel), (loss, det)
         return new_carry, (loss, det)
 
-    if fused:
-        init = (W0, cw0)
-        xs_scan = (xs, com["keys"])
-    elif gram:
-        init = jnp.zeros_like(cw0)
-        xs_scan = (xs, com)
-    else:
-        init = W0
-        xs_scan = (xs, com)
+    init = jnp.zeros_like(cw0) if gram else W0
     if telemetry:
         init = (init, {k: jnp.zeros(B, jnp.int32) for k in TEL_KEYS})
-        (fin, tel), (losses, det) = jax.lax.scan(host_step, init, xs_scan)
+        (fin, tel), (losses, det) = jax.lax.scan(host_step, init, (xs, com))
     else:
-        fin, (losses, det) = jax.lax.scan(host_step, init, xs_scan)
+        fin, (losses, det) = jax.lax.scan(host_step, init, (xs, com))
         tel = None
-    if fused:
-        W, cw = fin
-        # the last step's update is still pending: one final contraction
-        W = W - jnp.dot(cw, A.astype(jnp.float32), precision=HIGHEST,
-                        preferred_element_type=jnp.float32)
-    elif gram:
+    if gram:
         # the only d-sized work of the whole run: W_T = W0 - C_T @ R
         W = W0 - jnp.dot(fin, A["rows"].astype(jnp.float32),
                          precision=HIGHEST,
@@ -602,13 +565,13 @@ def step_core(A, y, W0, cw0, stat, xs, com, noisevec, pid, *,
     return W, losses, det
 
 
-# the single-device entry: one jit whose cache keys on the plan statics —
-# replaces the three separate jitted cores.  Per-chunk buffers (W0, cw0,
-# stat, xs) are freshly uploaded each chunk and donated; chunk-invariant
-# operands (A/rows, y, com, noisevec, pid) are reused and never donated.
+# the single-device entry: one jit whose cache keys on the plan statics.
+# Per-chunk buffers (W0, cw0, stat, xs) are freshly uploaded each chunk
+# and donated; chunk-invariant operands (A/rows, y, com, noisevec, pid)
+# are reused and never donated.
 jitted_step_core = functools.partial(
     jax.jit,
-    static_argnames=("fused", "control", "shared", "has_filter",
+    static_argnames=("control", "shared", "has_filter",
                      "has_bias", "impl", "gram", "telemetry"),
     donate_argnames=("W0", "cw0", "stat", "xs"),
 )(step_core)

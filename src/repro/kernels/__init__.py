@@ -3,10 +3,8 @@
   sketch           CountSketch detection symbol (O(k) BFT detection traffic)
   majority_vote    blockwise pairwise replica agreement (reactive 2f+1 vote)
   coded_encode     linear detection-code encode (generalized Fig-2 codes)
-  fused_step       one-pass protocol-step megakernel: pending-update
-                   contraction + residual symbols + detection sketch in a
-                   single HBM pass over the (B, d) state (the jitted
-                   engine's fused data plane)
+  gram             one-pass gram-plane precompute: G = R R^T and every
+                   step's sketch table of the data rows
   flash_attention  fused blockwise attention forward (GQA, causal/window)
 
 Each kernel: pl.pallas_call + explicit BlockSpec VMEM tiling, a jit'd

@@ -13,14 +13,13 @@ pass, every d-sized quantity the gram-domain scan will ever need:
       directly);
   (c) the per-step CountSketch tables ``SK[t] = CountSketch_k(R)``
       under ``keys[t]`` for every protocol step t — the tables the
-      stream plane either pre-sketches in T separate passes (unfused)
-      or rebuilds once per step inside the megakernel (fused).
+      stream plane pre-sketches in T separate passes.
 
 All three are constant-``index_map`` VMEM accumulators revisited every
-grid step (``pl.when(j == 0)`` zero-init — the accumulator idiom of
-``fused_step.py``).  The sketch signs are rematerialized in-register
-from the global column position with ``ref.hash_signs_ref``'s hash, so
-(c) is bitwise the same bucket layout as the stream plane's tables.
+grid step (``pl.when(j == 0)`` zero-init).  The sketch signs are
+rematerialized in-register from the global column position with
+``ref.hash_signs_ref``'s hash, so (c) is bitwise the same bucket layout
+as the stream plane's tables.
 
 The (T, Ie, k) sketch accumulator must fit VMEM alongside the rows
 block: ~``T * Ie_p * k * 4`` bytes (≈7.4 MB at T=100, Ie_p=72, k=256).

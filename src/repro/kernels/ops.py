@@ -30,7 +30,6 @@ import jax.numpy as jnp
 
 from repro.kernels import coded_encode as _enc
 from repro.kernels import flash_attention as _fa
-from repro.kernels import fused_step as _fs
 from repro.kernels import gram as _gm
 from repro.kernels import majority_vote as _mv
 from repro.kernels import ref as _ref
@@ -303,62 +302,6 @@ def batched_sketch(flat_g, key_scalar, k: int = 256, *,
         return _shard_batched(kern, (flat_g, jnp.asarray(key_scalar)),
                               (True, False), 2)
     return _sketch_xla(flat_g, key_scalar, k)
-
-
-def fused_step(rows, W, cw, key_scalar, *, k: int = 256,
-               impl: str | None = None, interpret: bool | None = None):
-    """One fused protocol-step pass over the data plane.
-
-    (rows (Ie, d) f32/bf16, W (B, d) f32, cw (B, Ie) f32, key) ->
-    (W - cw @ rows, (W - cw @ rows) @ rows^T, CountSketch_k(rows)) —
-    the pending-update contraction, the new residual symbols, and the
-    step's detection-sketch table, all in ONE HBM pass over the
-    gradient state (repro.kernels.fused_step; oracle:
-    ref.fused_step_ref).  ``"pallas"`` is the Mosaic megakernel
-    (interpret mode off-TPU); ``"xla"`` is a single jitted fallback.
-    Under an ambient trials mesh the pallas branch shards W/cw/resid
-    over the leading trial axis (rows and the sketch table replicate —
-    every device computes the identical sk from the same rows).
-    """
-    if _batched_impl(impl) == "pallas":
-        kern = functools.partial(
-            _fs.fused_step, k=k,
-            interpret=_interpret(interpret),
-        )
-        from jax.sharding import PartitionSpec as P
-
-        from repro.sharding import (
-            ambient_mesh, mesh_axis_size_here, shard_map,
-        )
-
-        ntr = mesh_axis_size_here("trials")
-        if ntr > 1 and W.shape[0] % ntr == 0:
-            trial2 = P("trials", None)
-            fn = shard_map(
-                kern, ambient_mesh(),
-                in_specs=(P(None, None), trial2, trial2, P()),
-                out_specs=(trial2, trial2, P(None, None)),
-                axis_names={"trials"}, check_vma=False)
-            return fn(rows, W, cw, jnp.asarray(key_scalar, jnp.uint32))
-        return kern(rows, W, cw, key_scalar)
-    return _fused_step_xla(rows, W, cw, key_scalar, k)
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _fused_step_xla(rows, W, cw, key_scalar, k):
-    rows32 = rows.astype(jnp.float32)
-    W_new = W.astype(jnp.float32) - jnp.dot(
-        cw, rows32, precision=_HIGHEST, preferred_element_type=jnp.float32)
-    resid = jax.lax.dot_general(W_new, rows32, (((1,), (1,)), ((), ())),
-                                precision=_HIGHEST,
-                                preferred_element_type=jnp.float32)
-    Ie, d = rows32.shape
-    pad = (-d) % k
-    g = jnp.pad(rows32, ((0, 0), (0, pad)))
-    idx = jax.lax.iota(jnp.uint32, d + pad)
-    sk = (g * _ref.hash_signs_ref(idx, key_scalar)[None]).reshape(
-        Ie, -1, k).sum(axis=1)
-    return W_new, resid, sk
 
 
 # VMEM budget for the gram kernel's (T, Ie_p, k) sketch accumulator;

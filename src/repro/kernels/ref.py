@@ -131,25 +131,6 @@ def batched_coded_encode_ref(coeffs: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Fused protocol step — see repro.kernels.fused_step
-# ---------------------------------------------------------------------------
-
-def fused_step_ref(rows: jnp.ndarray, W: jnp.ndarray, cw: jnp.ndarray,
-                   key_scalar, k: int = 256):
-    """Composed oracle for the fused megakernel: the three passes it
-    fuses, each expressed through the existing single-op refs.
-
-    W' = W - coded_encode(cw, rows);  resid = W' @ rows^T (the same
-    contraction, transposed);  sk = per-row CountSketch of the data rows.
-    """
-    rows32 = rows.astype(jnp.float32)
-    W_new = W.astype(jnp.float32) - coded_encode_ref(cw, rows32)
-    resid = coded_encode_ref(W_new, rows32.T)
-    sk = batched_sketch_ref(rows32, key_scalar, k)
-    return W_new, resid, sk
-
-
-# ---------------------------------------------------------------------------
 # Gram-plane precompute — see repro.kernels.gram
 # ---------------------------------------------------------------------------
 

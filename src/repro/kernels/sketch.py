@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 
 DEFAULT_K = 256
 ROWS_PER_STEP = 512
+SUBLANES = 8         # f32 rows of one TPU vector register
 
 
 def _sketch_kernel(g_ref, key_ref, o_ref, *, k: int, rows: int):
@@ -109,6 +110,11 @@ def sketch_batched(flat_g: jnp.ndarray, key_scalar, k: int = DEFAULT_K,
     pad = (-d) % k
     g = jnp.pad(flat_g, ((0, 0), (0, pad))).reshape(B, -1, k)
     t = g.shape[1]
+    if t <= SUBLANES:
+        # a row of at most 8 tiles (d <= 8k) fits one 8-sublane block:
+        # the same sums as a block padded to rows_per_step, which would
+        # read rows_per_step / 8 times the bytes, nearly all zeros
+        rows_per_step = min(rows_per_step, SUBLANES)
     pad_t = (-t) % rows_per_step
     g = jnp.pad(g, ((0, 0), (0, pad_t), (0, 0)))
     nsteps = g.shape[1] // rows_per_step

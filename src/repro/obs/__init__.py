@@ -8,9 +8,7 @@ docs/observability.md):
   carry (detections, votes, eliminations, tamper events, the paper's
   redundancy-overhead fraction), returned as ``BatchResult.telemetry``;
 * :mod:`repro.obs.trace` — host span tracing (a context manager whose
-  spans are also profiler annotations) with Chrome-trace JSON export
-  and the ``profile_trace`` hook that nests ``jax.profiler.trace``
-  under ``REPRO_PROFILE``;
+  spans are also profiler annotations) with Chrome-trace JSON export;
 * :mod:`repro.obs.metrics` — a process-wide counter/gauge registry
   with JSONL export.
 
@@ -28,4 +26,4 @@ from repro.obs import metrics, oblog, telemetry, trace  # noqa: F401
 from repro.obs.metrics import REGISTRY  # noqa: F401
 from repro.obs.oblog import reset_warn_once, warn_once  # noqa: F401
 from repro.obs.telemetry import TEL_KEYS, Telemetry  # noqa: F401
-from repro.obs.trace import TRACER, profile_trace, span  # noqa: F401
+from repro.obs.trace import TRACER, span  # noqa: F401

@@ -24,7 +24,7 @@ def warn_once(message: str, category: type[Warning] = UserWarning, *,
 
     ``key`` defaults to ``(category name, message)``; pass an explicit
     key to dedup across varying message decorations (e.g. one warning
-    per distinct ``fallback_reason``).  Returns True when the warning
+    per distinct ``data_plane_reason``).  Returns True when the warning
     was emitted, False when suppressed as a duplicate.
     """
     k = (category.__name__, message) if key is None else key

@@ -5,7 +5,7 @@ scan and its host-side container.
 Inside the scan the counters live as a ``{key: (B,) int32}`` dict
 appended to the carry (``telemetry=True`` on ``run_batch``); every
 execution path — numpy oracle, jax host-control, jax device-control,
-stream/fused/gram, sharded or not — accumulates the SAME quantities so
+stream/gram, sharded or not — accumulates the SAME quantities so
 the differential suite can assert exact integer equality across
 backends.  On the host the counters are widened to int64 and wrapped in
 :class:`Telemetry` together with the q_t summary statistics (taken from

@@ -1,6 +1,5 @@
 """Host span tracing: lightweight wall-clock spans with Chrome-trace
-export, plus the ``profile_trace`` hook that generalizes the benchmark
-harness' old private ``_profiled`` helper.
+export.
 
 Spans record into a bounded in-process ring buffer (no I/O on the hot
 path, no background thread); :func:`export_chrome` writes the buffer as
@@ -8,11 +7,7 @@ Chrome-trace JSON ("X" complete events) loadable in ``chrome://tracing``
 / Perfetto.  Every span is also a ``jax.profiler.TraceAnnotation`` of
 the same name, so while a JAX profiler trace runs the spans land in its
 host plane on the device trace's clock (about a microsecond a span when
-no profiler runs).  ``profile_trace`` additionally nests
-``jax.profiler.trace(<dir>/<label>)`` when ``REPRO_PROFILE=<dir>`` is
-set (or an explicit ``profile_dir`` is passed) so kernel/HBM-level
-traces line up with the host spans — the single implementation shared
-by ``benchmarks/bench_protocol.py`` and ``benchmarks/run.py``.
+no profiler runs).
 """
 from __future__ import annotations
 
@@ -110,25 +105,3 @@ dropped = TRACER.dropped
 clear = TRACER.clear
 export_chrome = TRACER.export_chrome
 
-
-@contextlib.contextmanager
-def profile_trace(label: str, profile_dir: str | None = None):
-    """Span + opt-in ``jax.profiler.trace`` around the enclosed block.
-
-    Always records an obs span named ``label``.  When
-    ``REPRO_PROFILE=<dir>`` is set (or ``profile_dir`` is passed
-    explicitly), additionally wraps the block in
-    ``jax.profiler.trace(<dir>/<label>)`` so fused-vs-unfused HBM
-    traffic (and every kernel launch) is inspectable in TensorBoard /
-    Perfetto; without it, the profiler side is a no-op.
-    """
-    prof_dir = (os.environ.get("REPRO_PROFILE") if profile_dir is None
-                else profile_dir)
-    with TRACER.span(label, profiled=bool(prof_dir)):
-        if not prof_dir:
-            yield
-            return
-        import jax
-
-        with jax.profiler.trace(os.path.join(prof_dir, label)):
-            yield

@@ -19,7 +19,7 @@ sys.path.insert(0, ROOT)
 import chip_smoke as cs  # noqa: E402
 
 TINY_ENGINE = {
-    "engine_fused": dict(trials=8, d=1 << 10, steps=3, n_ref=4),
+    "engine_stream": dict(trials=8, d=1 << 10, steps=3, n_ref=4),
     "engine_gram": dict(trials=4, d=1 << 10, steps=12, n_ref=2),
     "engine_device_control": dict(trials=16, d=1 << 8, steps=8, n_ref=8),
 }
@@ -46,7 +46,7 @@ def test_engine_phase(pallas, phase):
 def test_engine_phase_fails_on_a_value_mismatch(pallas, monkeypatch):
     """A reference that disagrees in values fails the comparison."""
     monkeypatch.setattr(cs, "_sup_dev", lambda a, b: 1.0)
-    line = cs.engine_fused(**TINY_ENGINE["engine_fused"])
+    line = cs.engine_stream(**TINY_ENGINE["engine_stream"])
     assert not line["checks"]["ok"]
 
 

@@ -246,44 +246,6 @@ def test_jax_backend_zero_steps_returns_real_problem():
     assert rj.losses == rn.losses == []
 
 
-@pytest.mark.parametrize("name", _scenario_names())
-def test_jax_backend_fused_vs_unfused(name):
-    """fused=True (default) vs fused=False (the parity oracle): control
-    quantities exact, values at the f32-vs-f32 tolerance — across the
-    whole SCENARIOS grid, wherever the fused gate engages."""
-    from repro.core.engine import SCENARIOS
-
-    _, jfu = _both_backends(name)               # default: fused=True
-    jun = SCENARIOS[name].run(backend="jax", fused=False)
-    assert jun.fused_used is False
-    for ru, rf in zip(jun, jfu):
-        assert ru.identify_step == rf.identify_step
-        assert ru.efficiency == rf.efficiency
-        assert ru.q_trace == rf.q_trace
-        np.testing.assert_allclose(rf.w, ru.w, rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(rf.losses),
-                                   np.asarray(ru.losses),
-                                   rtol=1e-5, atol=1e-5)
-    assert np.array_equal(jfu.detect_flags, jun.detect_flags)
-
-
-def test_jax_backend_fused_scope_gate():
-    """fused_used reports which path ran: on for the shared-problem
-    host-schedule hot path, silently off for filter trials, mixed
-    problems, schedule="device", and fused=False."""
-    hot = [TrialSpec(byz=(2,), attack="drift", steps=12, q=0.5, seed=1)]
-    assert run_batch(hot, backend="jax").fused_used is True
-    assert run_batch(hot, backend="jax", fused=False).fused_used is False
-    assert run_batch(hot, backend="jax",
-                     schedule="device").fused_used is False
-    filt = [TrialSpec(byz=(2,), attack="drift", steps=12, q=0.5,
-                      mode="filter:median")]
-    assert run_batch(filt, backend="jax").fused_used is False
-    mixed = hot + [TrialSpec(byz=(2,), attack="drift", steps=12, q=0.5,
-                             seed=2, problem_seed=3)]
-    assert run_batch(mixed, backend="jax").fused_used is False
-
-
 # ===========================================================================
 # Gram data plane: the coefficient-space scan (resid = S0 - C_t G) must
 # reproduce the stream plane's control quantities bit-for-bit and its
@@ -294,14 +256,14 @@ def test_jax_backend_fused_scope_gate():
 
 
 @pytest.mark.parametrize("name", _scenario_names())
-def test_jax_backend_gram_vs_fused_vs_unfused(name):
+def test_jax_backend_gram_vs_stream(name):
     import warnings
 
     from repro.core.engine import SCENARIOS
     from repro.core.engineplan.plan import PlanFallbackWarning
 
-    _, jfu = _both_backends(name)               # default (fused on-grid)
-    jun = SCENARIOS[name].run(backend="jax", fused=False)
+    _, jst = _both_backends(name)               # default: stream at d=8
+    assert jst.plan.data_plane == "stream"
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", PlanFallbackWarning)
         jgr = SCENARIOS[name].run(backend="jax", data_plane="gram")
@@ -310,23 +272,22 @@ def test_jax_backend_gram_vs_fused_vs_unfused(name):
         assert jgr.plan.data_plane == "stream"
         return
     assert jgr.plan.data_plane == "gram"
-    assert jgr.fused_used is False
-    for rg, rf, ru in zip(jgr, jfu, jun):
-        # control plane: exact three-way agreement
-        assert rg.identify_step == rf.identify_step == ru.identify_step
-        assert rg.efficiency == rf.efficiency == ru.efficiency
-        assert rg.q_trace == ru.q_trace
+    for rg, rs in zip(jgr, jst):
+        # control plane: exact agreement
+        assert rg.identify_step == rs.identify_step
+        assert rg.efficiency == rs.efficiency
+        assert rg.q_trace == rs.q_trace
         # value plane: f32-vs-f32 tolerance
-        np.testing.assert_allclose(rg.w, ru.w, rtol=JAX_W_RTOL,
+        np.testing.assert_allclose(rg.w, rs.w, rtol=JAX_W_RTOL,
                                    atol=JAX_W_ATOL)
         np.testing.assert_allclose(np.asarray(rg.losses),
-                                   np.asarray(ru.losses),
+                                   np.asarray(rs.losses),
                                    rtol=JAX_LOSS_RTOL, atol=JAX_LOSS_ATOL)
     # sketch-detection verdicts: bitwise (same precomputed tables, same
-    # einsum arithmetic as the unfused pre-sketched stream)
-    assert np.array_equal(jgr.detect_flags, jun.detect_flags)
+    # einsum arithmetic as the stream plane's pre-sketched rows)
+    assert np.array_equal(jgr.detect_flags, jst.detect_flags)
     for k, v in jgr.schedule.arrays.items():
-        assert np.array_equal(v, jun.schedule.arrays[k]), k
+        assert np.array_equal(v, jst.schedule.arrays[k]), k
 
 
 def test_jax_backend_gram_auto_engages_at_production_d():
@@ -335,8 +296,7 @@ def test_jax_backend_gram_auto_engages_at_production_d():
     # lr is scaled to the least-squares Lipschitz constant (~d/n_data):
     # the TrialSpec default lr=0.05 makes GD divergent at this d, and
     # exponentially growing iterates amplify basis-order rounding past
-    # any meaningful value tolerance (the gram_sweep bench scales lr the
-    # same way)
+    # any meaningful value tolerance
     specs = [TrialSpec(byz=(2, 5), attack="sign_flip", steps=40, q=0.4,
                        seed=1, n_data=64, d=4096, lr=64.0 / 4096),
              TrialSpec(byz=(3,), attack="drift", steps=40, q=0.5,
@@ -403,28 +363,6 @@ def test_jax_backend_gram_device_control():
         np.testing.assert_allclose(rg.q_trace, rs.q_trace,
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(rg.w, rs.w, rtol=1e-4, atol=1e-4)
-
-
-def test_jax_backend_bf16_stream():
-    """bf16 data streaming: control plane still exact (it is computed
-    from the host schedule), values at a loosened tolerance."""
-    specs = [
-        TrialSpec(byz=(2, 5), attack="sign_flip", steps=60, q=0.4, seed=1),
-        TrialSpec(byz=(3,), attack="drift", steps=60, q=0.5, seed=2),
-    ]
-    npb = run_batch(specs)
-    jxb = run_batch(specs, backend="jax", stream_dtype="bf16")
-    assert jxb.fused_used is True
-    for rn, rj in zip(npb, jxb):
-        assert rn.identify_step == rj.identify_step
-        assert rn.q_trace == rj.q_trace
-        np.testing.assert_allclose(rj.w, np.asarray(rn.w),
-                                   rtol=3e-2, atol=3e-2)
-
-
-def test_jax_backend_rejects_bad_stream_dtype():
-    with pytest.raises(ValueError, match=r"f16.*f32.*bf16"):
-        run_batch([TrialSpec(steps=2)], backend="jax", stream_dtype="f16")
 
 
 def test_jax_backend_mixed_batch():

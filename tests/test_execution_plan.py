@@ -1,10 +1,10 @@
 """Unit grid over the ExecutionPlan layer (repro.core.engineplan.plan).
 
-``resolve_plan`` is pure, so every path decision — schedule mode, fused
-engagement, sharding, chunk sizing — is asserted here for the full
+``resolve_plan`` is pure, so every path decision — schedule mode, data
+plane, sharding, chunk sizing — is asserted here for the full
 ``SCENARIOS`` matrix without touching a device.  The one warning path
-that needs the real engine (``FusedFallbackWarning`` on an explicit
-``fused=True`` demotion) runs a tiny jax-backend batch at the end.
+that needs the real engine (``PlanFallbackWarning`` on an explicit
+``data_plane="gram"`` demotion) runs a tiny jax-backend batch.
 """
 from __future__ import annotations
 
@@ -17,7 +17,6 @@ import pytest
 
 from repro.core.engine import SCENARIOS, TrialSpec
 from repro.core.engineplan.plan import (
-    FusedFallbackWarning,
     PlanFallbackWarning,
     device_schedulable,
     resolve_plan,
@@ -38,18 +37,18 @@ def _spec(**kw) -> TrialSpec:
 # resolve_plan over the full SCENARIOS matrix
 # ---------------------------------------------------------------------------
 
-# expected (schedule_mode, fused, sharded) per scenario under default
-# knobs (schedule="auto", fused=None, single device).  Every scenario
-# holds at least one value-DEPENDENT trial (adaptive q*, or a
-# detectability-scaling attack vs an active adversary), so "auto"
-# resolves to the oracle replay batch-wide; fused engages everywhere the
-# batch is shared-problem and filter-free.
+# expected (schedule_mode, data_plane, sharded) per scenario under
+# default knobs (schedule="auto", data_plane=None, single device).
+# Every scenario holds at least one value-DEPENDENT trial (adaptive q*,
+# or a detectability-scaling attack vs an active adversary), so "auto"
+# resolves to the oracle replay batch-wide; every scenario runs at the
+# default tiny d=8, below gram's size gate, so the stream plane runs.
 _EXPECT = {
-    "paper_core": ("oracle", False, False),      # filter baselines demote
-    "attack_sweep": ("oracle", True, False),
-    "late_onset": ("oracle", True, False),
-    "elastic_churn": ("oracle", True, False),
-    "selective": ("oracle", True, False),
+    "paper_core": ("oracle", "stream", False),   # filter baselines too
+    "attack_sweep": ("oracle", "stream", False),
+    "late_onset": ("oracle", "stream", False),
+    "elastic_churn": ("oracle", "stream", False),
+    "selective": ("oracle", "stream", False),
 }
 
 
@@ -57,16 +56,14 @@ _EXPECT = {
 def test_scenarios_grid_default_plan(name):
     specs = SCENARIOS[name].expand()
     plan = resolve_plan(specs)
-    assert (plan.schedule_mode, plan.fused, plan.sharded) == _EXPECT[name]
+    assert (plan.schedule_mode, plan.data_plane,
+            plan.sharded) == _EXPECT[name]
     assert plan.control == "host"
     assert plan.n_devices == 1
     assert plan.n_trials == len(specs)
     assert plan.steps == max(s.steps for s in specs)
     assert plan.shared_problem is True
-    if plan.fused:
-        assert plan.fallback_reason is None
-    else:
-        assert plan.fallback_reason is not None
+    assert plan.data_plane_reason            # why not gram is recorded
 
 
 @pytest.mark.parametrize("name", sorted(SCENARIOS))
@@ -77,7 +74,7 @@ def test_scenarios_grid_forced_8_device_mesh(name):
     assert plan.n_devices == 8
     assert plan.chunk_trials % 8 == 0           # mesh-multiple rounding
     # sharding never changes the path selection itself
-    assert (plan.schedule_mode, plan.fused) == _EXPECT[name][:2]
+    assert (plan.schedule_mode, plan.data_plane) == _EXPECT[name][:2]
 
 
 def test_value_independent_subset_takes_vector():
@@ -87,7 +84,7 @@ def test_value_independent_subset_takes_vector():
              if s.attack == "drift" and s.q is not None]
     assert specs and all(value_independent_control(s) for s in specs)
     plan = resolve_plan(specs)
-    assert (plan.schedule_mode, plan.fused) == ("vector", True)
+    assert (plan.schedule_mode, plan.data_plane) == ("vector", "stream")
 
 
 def test_device_schedule_plan():
@@ -95,8 +92,8 @@ def test_device_schedule_plan():
     assert all(device_schedulable(s) for s in specs)
     plan = resolve_plan(specs, schedule="device")
     assert (plan.schedule_mode, plan.control) == ("device", "device")
-    assert plan.fused is False
-    assert "host-schedule" in plan.fallback_reason
+    assert plan.data_plane == "stream"
+    assert "coin-flip sliver" in plan.data_plane_reason
 
 
 # ---------------------------------------------------------------------------
@@ -161,59 +158,33 @@ def test_device_error_names_offending_spec():
 
 
 # ---------------------------------------------------------------------------
-# fused fallback: recorded reason, explain(), warning
+# data-plane fallback: recorded reason, explain(), warning
 # ---------------------------------------------------------------------------
 
 
-def test_explain_names_fused_fallback():
-    specs = [_spec(), _spec(mode="filter:krum", label="krum-baseline")]
-    plan = resolve_plan(specs, fused=True)
-    assert plan.fused is False
-    assert "krum-baseline" in plan.fallback_reason
-    text = plan.explain()
-    assert "requested but demoted" in text
-    assert "krum-baseline" in text
-
-
 def test_explain_on_and_off_paths():
-    on = resolve_plan([_spec()])
-    assert "fused    : ON" in on.explain()
-    off = resolve_plan([_spec()], fused=False)
-    assert "disabled by fused=False" in off.explain()
+    on = resolve_plan([_spec(n_data=64, d=4096)])
+    assert "data     : gram — shared problem" in on.explain()
+    off = resolve_plan([_spec(n_data=64, d=4096)], data_plane="stream")
+    assert 'not gram: data_plane="stream" requested' in off.explain()
 
 
 def test_auto_fallback_records_reason_without_warning():
-    plan = resolve_plan([_spec(mode="filter:median")])   # fused=None auto
-    assert plan.fused is False
-    assert "filter baseline" in plan.fallback_reason
+    plan = resolve_plan([_spec(mode="filter:median")])   # auto plane
+    assert plan.data_plane == "stream"
+    assert "filter baseline" in plan.data_plane_reason
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         warn_on_fallback(plan)                           # no warning: auto
 
 
 def test_zero_steps_never_warns():
-    plan = resolve_plan([_spec(steps=0, mode="filter:median")], fused=True)
-    assert plan.fused is False
+    plan = resolve_plan([_spec(steps=0, mode="filter:median")],
+                        data_plane="gram")
+    assert plan.data_plane == "stream"
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         warn_on_fallback(plan)
-
-
-def test_explicit_fused_demotion_warns():
-    plan = resolve_plan([_spec(mode="filter:median")], fused=True)
-    with pytest.warns(FusedFallbackWarning, match="filter baseline"):
-        warn_on_fallback(plan)
-
-
-def test_engine_emits_fused_fallback_warning():
-    from repro.core.engine import run_batch
-
-    specs = [dataclasses.replace(_spec(), steps=3, mode="filter:median")]
-    with pytest.warns(FusedFallbackWarning, match="filter baseline"):
-        out = run_batch(specs, backend="jax", fused=True)
-    assert out.fused_used is False
-    assert out.plan.fused is False
-    assert out.plan.fused_requested is True
 
 
 def test_engine_result_carries_plan():
@@ -221,8 +192,7 @@ def test_engine_result_carries_plan():
 
     out = run_batch([_spec(steps=3)], backend="jax")
     assert out.plan is not None
-    assert out.plan.fused is True
-    assert out.fused_used is out.plan.fused      # compat mirror
+    assert out.plan.data_plane == "stream"
     assert "ExecutionPlan[backend=jax" in out.plan.explain()
 
 
@@ -245,8 +215,6 @@ def test_scenarios_grid_stays_on_stream_plane(name):
 def test_auto_gram_engages_at_large_d():
     plan = resolve_plan([_spec(n_data=64, d=4096)])
     assert plan.data_plane == "gram"
-    assert plan.fused is False
-    assert "superseded by the gram data plane" in plan.fallback_reason
     text = plan.explain()
     assert "gram — shared problem" in text
     assert "I=66" in plan.data_plane_reason
@@ -256,14 +224,6 @@ def test_auto_gram_size_gate_keeps_stream():
     plan = resolve_plan([_spec(n_data=64, d=64)])
     assert plan.data_plane == "stream"
     assert "d=64 < 4*I=264" in plan.data_plane_reason
-    assert plan.fused is True                # the stream fast path stays
-
-
-def test_auto_gram_defers_to_explicit_fused():
-    plan = resolve_plan([_spec(n_data=64, d=4096)], fused=True)
-    assert plan.data_plane == "stream"
-    assert "pins the stream data plane" in plan.data_plane_reason
-    assert plan.fused is True
 
 
 def test_auto_gram_keeps_stream_under_device_control():
@@ -298,24 +258,9 @@ def test_explicit_gram_zero_steps_never_warns():
         warn_on_fallback(plan)
 
 
-def test_gram_with_fused_true_rejected():
-    with pytest.raises(ValueError, match="conflicts with fused=True"):
-        resolve_plan([_spec()], data_plane="gram", fused=True)
-
-
 def test_unknown_data_plane_rejected():
     with pytest.raises(ValueError, match="unknown data_plane"):
         resolve_plan([_spec()], data_plane="coefficients")
-
-
-def test_fused_warning_is_plan_fallback_subclass():
-    # deprecation shim: old filters catching FusedFallbackWarning keep
-    # matching fused demotions; new code catches PlanFallbackWarning
-    # and sees every demotion class
-    assert issubclass(FusedFallbackWarning, PlanFallbackWarning)
-    plan = resolve_plan([_spec(mode="filter:median")], fused=True)
-    with pytest.warns(PlanFallbackWarning, match="filter baseline"):
-        warn_on_fallback(plan)
 
 
 def test_engine_emits_plan_fallback_warning_on_gram_demotion():

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, sketch
 
 IMPLS = ("pallas", "xla")
 
@@ -110,6 +110,19 @@ def test_batched_sketch(impl, B, d):
                                rtol=2e-5, atol=1e-3)
 
 
+@pytest.mark.parametrize("d", [8, 255, 1024, 2048, 2049])
+def test_sketch_batched_rows_equal_single_kernel_bitwise(d):
+    """A row of at most 8 tiles (d <= 2048) takes an 8-row block, where
+    the single-vector kernel streams a 512-row one of zeros: the sums
+    must not move by a bit (d = 2049 takes the 512-row block too)."""
+    g = jax.random.normal(jax.random.PRNGKey(d), (3, d), jnp.float32)
+    got = sketch.sketch_batched(g, 4242, interpret=True)
+    for b in range(3):
+        np.testing.assert_array_equal(
+            np.asarray(got[b]),
+            np.asarray(sketch.sketch(g[b], 4242, interpret=True)))
+
+
 def test_relmax_xla_chunking_matches_unchunked():
     """The memory-bounded XLA fallback folds d in chunks; values must
     equal the naive reference regardless of the chunk boundary."""
@@ -157,82 +170,6 @@ def test_batched_vote_matches_majority_vote_np(impl):
     # exactly one winner per group, none among idle workers
     assert coeff[0].sum() == 2 and coeff[1].sum() == 2
     assert not coeff[group < 0].any()
-
-
-# ---------------------------------------------------------------------------
-# fused protocol-step megakernel vs the composed single-op oracles
-# ---------------------------------------------------------------------------
-
-
-def _fused_inputs(B, Ie, d, seed, rows_dtype=jnp.float32):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    rows = jax.random.normal(ks[0], (Ie, d), jnp.float32).astype(rows_dtype)
-    W = jax.random.normal(ks[1], (B, d), jnp.float32)
-    cw = jax.random.normal(ks[2], (B, Ie), jnp.float32)
-    return rows, W, cw
-
-
-@pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("B,Ie,d", [
-    (1, 3, 8),            # B = 1 singleton batch, tiny d
-    (2, 10, 511),         # d off the 512 block AND off the 256 sketch lane
-    (3, 7, 513),          # just past one block
-    (2, 8, 1024),         # exact block multiple (in-place aliasing path)
-])
-def test_fused_step_vs_composed_refs(impl, B, Ie, d):
-    rows, W, cw = _fused_inputs(B, Ie, d, seed=B + Ie + d)
-    W_k, resid_k, sk_k = ops.fused_step(rows, W, cw, 1234, impl=impl,
-                                        interpret=True)
-    W_r, resid_r, sk_r = ref.fused_step_ref(rows, W, cw, 1234)
-    np.testing.assert_allclose(W_k, W_r, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(resid_k, resid_r, rtol=1e-5, atol=1e-4)
-    np.testing.assert_allclose(sk_k, sk_r, rtol=2e-5, atol=1e-3)
-
-
-@pytest.mark.parametrize("impl", IMPLS)
-def test_fused_step_zero_coeffs_keep_iterate_bitwise(impl):
-    """A zero coefficient row (dead trial / zero active workers) must
-    leave the iterate BITWISE unchanged — the engine folds the live
-    mask and lr into cw and relies on 0-row contractions being exact."""
-    rows, W, _ = _fused_inputs(3, 6, 1024, seed=11)
-    cw = jnp.zeros((3, 6), jnp.float32)
-    W_k, resid_k, _ = ops.fused_step(rows, W, cw, 7, impl=impl,
-                                     interpret=True)
-    np.testing.assert_array_equal(np.asarray(W_k), np.asarray(W))
-    np.testing.assert_allclose(
-        resid_k, ref.coded_encode_ref(W, jnp.asarray(rows).T),
-        rtol=1e-5, atol=1e-4)
-
-
-@pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("d", [511, 1024])
-def test_fused_step_bf16_stream(impl, d):
-    """bf16-stored rows at loosened tolerance: both the kernel and the
-    oracle read the SAME bf16 values, so the only drift is summation
-    order, but the contractions amplify rounding — hence the loose rtol
-    vs the fp32 run of the same data."""
-    rows, W, cw = _fused_inputs(2, 8, d, seed=d, rows_dtype=jnp.bfloat16)
-    W_k, resid_k, sk_k = ops.fused_step(rows, W, cw, 99, impl=impl,
-                                        interpret=True)
-    W_r, resid_r, sk_r = ref.fused_step_ref(rows, W, cw, 99)
-    np.testing.assert_allclose(W_k, W_r, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(resid_k, resid_r, rtol=1e-4, atol=1e-3)
-    np.testing.assert_allclose(sk_k, sk_r, rtol=1e-4, atol=1e-2)
-    # and the bf16 stream stays close to the f32 stream of the same data
-    rows32, W2, cw2 = _fused_inputs(2, 8, d, seed=d)
-    W_f, _, _ = ops.fused_step(rows32, W2, cw2, 99, impl=impl,
-                               interpret=True)
-    np.testing.assert_allclose(W_k, W_f, rtol=3e-2, atol=3e-1)
-
-
-def test_fused_step_shape_validation():
-    rows, W, cw = _fused_inputs(2, 6, 64, seed=0)
-    from repro.kernels.fused_step import fused_step
-
-    with pytest.raises(ValueError, match="shape mismatch"):
-        fused_step(rows, W[:, :32], cw, 0, interpret=True)
-    with pytest.raises(ValueError, match="multiple"):
-        fused_step(rows, W, cw, 0, k=7, block_d=64, interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -295,7 +232,7 @@ def test_gram_factors_key_chunking_matches_unchunked(monkeypatch):
 
 def test_gram_factors_xla_tables_match_stream_plane():
     """The gram plane's detection verdicts rest on the per-step sketch
-    tables matching the values the unfused scan pre-sketches.  The xla
+    tables matching the values the stream plane pre-sketches.  The xla
     dispatch computes all T tables as one bucketed einsum, which sums
     each bucket in a different f32 order than the stream plane's
     per-key reshape(-1, k).sum — so the match is tight-tolerance, not

@@ -142,15 +142,6 @@ def test_export_chrome_trace_json(tmp_path):
     assert ev["dur"] >= 0 and ev["args"] == {"chunk": 1}
 
 
-def test_profile_trace_records_span_without_profiler(monkeypatch):
-    monkeypatch.delenv("REPRO_PROFILE", raising=False)
-    trace.clear()
-    with trace.profile_trace("bench_label"):
-        pass
-    ev = next(e for e in trace.spans() if e["name"] == "bench_label")
-    assert ev["args"] == {"profiled": False}
-
-
 # ---------------------------------------------------------------------------
 # warning dedup
 # ---------------------------------------------------------------------------

@@ -77,16 +77,9 @@ def test_sharded_equals_unsharded(results):
 
 
 @pytest.mark.slow
-def test_fused_sharded_parity(results):
-    """The fused megakernel path across the 8-device trials mesh agrees
-    with the unfused sharded oracle, and fused_used reports the path."""
-    assert results["fused_sharded_parity"] is True
-
-
-@pytest.mark.slow
 def test_gram_sharded_parity(results):
     """The gram data plane across the 8-device trials mesh: values match
-    the unfused sharded oracle at the f32 tolerance, detection verdicts
+    the stream plane's sharded run at the f32 tolerance, detection verdicts
     bitwise, and the chunked pipeline agrees with the one-chunk run."""
     assert results["gram_sharded_parity"] is True
     assert results["gram_chunk_pipeline_parity"] is True

@@ -1,7 +1,7 @@
 """End-to-end telemetry contract for ``run_batch(..., telemetry=True)``.
 
 Three guarantees, asserted across every execution path the engine has
-(numpy oracle, jax host-control stream/fused/gram, jax device-control):
+(numpy oracle, jax host-control stream/gram, jax device-control):
 
 1. *output-neutral* — turning telemetry on changes NOTHING about the
    primary outputs: final iterates bitwise identical, control decisions
@@ -85,7 +85,7 @@ def test_telemetry_off_is_none():
 
 
 # ---------------------------------------------------------------------------
-# the other execution paths: fused / gram / device control
+# the other execution paths: stream / gram / device control
 # ---------------------------------------------------------------------------
 
 
@@ -105,19 +105,16 @@ def _plane_specs():
 
 
 @pytest.mark.parametrize("kw", [
-    {"fused": True},
-    {"fused": False},
+    {"data_plane": "stream"},
     {"data_plane": "gram"},
-], ids=["fused", "stream", "gram"])
+], ids=["stream", "gram"])
 def test_data_planes_output_neutral_and_exact(kw):
     specs = [s for s in _plane_specs() if s.steps > 0]   # keep planes engaged
     np_on = run_batch(specs, telemetry=True)
     off = run_batch(specs, backend="jax", **kw)
     on = run_batch(specs, backend="jax", telemetry=True, **kw)
-    if "data_plane" in kw:
-        assert on.plan.data_plane == "gram" and off.plan.data_plane == "gram"
-    else:
-        assert on.fused_used is kw["fused"]
+    plane = kw["data_plane"]
+    assert on.plan.data_plane == plane and off.plan.data_plane == plane
     _assert_output_neutral(off, on)
     _assert_counters_equal(np_on.telemetry, on.telemetry, str(kw))
 
