@@ -96,6 +96,20 @@ from repro.obs.telemetry import Telemetry, zero_counts
 # releases the GIL around BLAS, so the slices run at once.  Each
 # primitive is written once, as the body of one slice: an unchunked
 # call runs it over every trial on the calling thread.
+#
+# One caller takes another path.  ``build_schedule``'s control oracle
+# reads only the control of its pass (the device recomputes every
+# iterate and loss), so on a shared problem past the pool's size gate it
+# runs the residuals and the phase-1 worker gradients as GEMMs over the
+# trials on the calling thread, where BLAS threads them
+# (``residuals_gemm``, ``worker_gradients_gemm``): each product then
+# reads the problem's data once a step, not once per trial, and the
+# gradients land in their workers' rows with no gather.  Their last bits
+# differ from the per-item calls'; the control they drive does not
+# (replicas of one shard stay equal bit for bit, and a q*_t that moves
+# by 1e-16 flips a coin only if its uniform lies that close).  Every
+# other pass, the numpy backend's above all, stays per item and bitwise
+# equal to ``run_protocol``.
 # ---------------------------------------------------------------------------
 
 # I·d, the elements of one trial's data, from which a product may go to
@@ -217,6 +231,51 @@ def shard_gradients(A_chunks: np.ndarray, resid_chunks: np.ndarray,
 
     _map_chunks(run, chunks)
     return out[:, :, 0, :]
+
+
+def residuals_gemm(A0: np.ndarray, y0: np.ndarray, W: np.ndarray,
+                   out: np.ndarray) -> np.ndarray:
+    """(I, d), (I,), (B, d) -> (B, I) residuals A0 w - y of every trial
+    on one shared problem, as one GEMM ``W @ A0.T`` on this thread.
+
+    ``out``: the (B, I, 1) scratch buffer of ``residuals``; the result
+    aliases it.  Rounds differently from ``residuals`` (see the module
+    comment on who may take it)."""
+    metrics.counter("engine.numpy.batched_products").inc()
+    o = out[:, :, 0]
+    np.matmul(W, A0.T, out=o)
+    np.subtract(o, y0, out=o)
+    return o
+
+
+def worker_gradients_gemm(A0: np.ndarray, resid: np.ndarray,
+                          shard_of_worker: np.ndarray,
+                          group_of_worker: np.ndarray,
+                          num_shards: np.ndarray,
+                          out: np.ndarray) -> np.ndarray:
+    """Every worker's least-squares shard gradient, of every trial on one
+    shared problem, as one GEMM per worker slot over the trials.
+
+    A0: (I, d); resid: (B, I); shard/group_of_worker: (B, n);
+    num_shards: (B,).  Worker w of trial b holds shard s of
+    rows = I // m rows, and gets 2/rows * A_s^T resid_s: the GEMM
+    multiplies A0 by the residuals scaled by 2/rows and masked to each
+    trial's shard rows, straight into the strided slice ``out[:, w]`` of
+    the (B, n, d) ``out``.  Idle workers (group -1) get zeros.  Replicas
+    of one shard read equal rows at the same place of GEMMs of one shape,
+    so they are equal bit for bit; the values round differently from
+    ``shard_gradients`` (see the module comment on who may take it)."""
+    metrics.counter("engine.numpy.batched_products").inc()
+    I = resid.shape[1]
+    rows = (I // num_shards)[:, None]                         # (B, 1)
+    scaled = 2.0 * resid / rows
+    col = _arange(I)[None, :]
+    for w in range(out.shape[1]):
+        lo = shard_of_worker[:, w, None] * rows
+        keep = (col >= lo) & (col < lo + rows) & (
+            group_of_worker[:, w, None] >= 0)
+        np.matmul(np.where(keep, scaled, 0.0), A0, out=out[:, w])
+    return out
 
 
 def worker_gradients(shard_g: np.ndarray, shard_of_worker: np.ndarray,
@@ -754,6 +813,7 @@ class ScheduleRecorder:
 def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
               rng: str = "host", telemetry: bool = False,
               _recorder: "ScheduleRecorder | None" = None,
+              _control_only: bool = False,
               **backend_kwargs) -> BatchResult:
     """Run B independent protocol trials in one vectorized pass.
 
@@ -784,6 +844,16 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
     seeded RNG stream exactly.  Everything on the every-step path —
     residuals, shard gradients, fixed-q check decisions, fast-mode
     assignments, weight aggregation, efficiency accounting — is batched.
+
+    ``_recorder`` and ``_control_only`` are for ``build_schedule``, the
+    control plane of the jax backend.  ``_recorder`` captures the pass's
+    per-step control (``ScheduleRecorder``).  ``_control_only=True``
+    says the floats of this pass are not results, only the control they
+    drive: on a shared problem whose trials' data I·d is at least
+    ``POOL_MIN_ITEM``, the residuals and phase-1 shard gradients then
+    run as GEMMs over the trials, whose iterates, losses and q*_t differ
+    from ``run_protocol``'s in the last bits (see the module comment on
+    the shared primitives).
     """
     from repro.core.simulation import SimResult, make_problem
 
@@ -947,6 +1017,9 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
     grads = np.empty((B, n_max, d))
     resid_buf = np.empty((B, n_data, 1))
     item = n_data * d            # a trial's data: sizes the pool's rule
+    # a control-only pass takes the shared problem's products as GEMMs
+    # over the trials, past the size gate that keeps small d per trial
+    gemm = _control_only and shared_problem and item >= POOL_MIN_ITEM
 
     live_const = np.ones(B, bool)
 
@@ -989,9 +1062,12 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
         # -- losses (shared residual also feeds the gradients) ------------
         # every float64 product over the problem's data runs under a
         # numpy.data span; the control work between them stays outside
-        with obtrace.span("numpy.data"):
-            resid = residuals(A_b, y_b, W, out=resid_buf,
-                              chunks=trial_chunks(B, item))  # (B, I)
+        with obtrace.span("numpy.data", product="residuals"):
+            if gemm:
+                resid = residuals_gemm(A0, y0, W, resid_buf)  # (B, I)
+            else:
+                resid = residuals(A_b, y_b, W, out=resid_buf,
+                                  chunks=trial_chunks(B, item))
             loss_col = losses_of(resid)                      # (B,)
             losses_mat[:, t] = loss_col
 
@@ -1065,32 +1141,38 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
         shard_all = batch_a.shard_of_worker
         m_all = batch_a.num_shards
 
-        # -- shard gradients: one batched matmul per distinct m -----------
-        with obtrace.span("numpy.data"):
-            for m in np.unique(m_all if live_all else m_all[live]):
-                m = int(m)
-                is_m = m_all == m
-                if not live_all:
-                    is_m &= live
-                sub = np.flatnonzero(is_m)
-                rows = n_data // m
-                if shared_problem:
-                    Ar = A0[: m * rows].reshape(1, m, rows, d)
-                else:
-                    Ar = A_b[sub, : m * rows].reshape(len(sub), m, rows, d)
-                rr = resid[sub, : m * rows].reshape(len(sub), m, 1, rows)
-                sg = shard_gradients(Ar, rr, rows,
-                                     trial_chunks(len(sub), item))  # (S, m, d)
-                if m == n_max and (group_all[sub] >= 0).all():
-                    # fast mode, nobody eliminated: worker w owns shard w —
-                    # the gather is the identity, skip it
-                    if sub.size == B:
-                        grads = sg
+        # -- worker gradients (B, n, d) -----------------------------------
+        with obtrace.span("numpy.data", product="shard_gradients"):
+            if gemm:    # one GEMM a worker slot, straight into grads
+                worker_gradients_gemm(A0, resid, shard_all, group_all,
+                                      m_all, out=grads)
+            else:       # one batched matmul per distinct m, then gather
+                for m in np.unique(m_all if live_all else m_all[live]):
+                    m = int(m)
+                    is_m = m_all == m
+                    if not live_all:
+                        is_m &= live
+                    sub = np.flatnonzero(is_m)
+                    rows = n_data // m
+                    if shared_problem:
+                        Ar = A0[: m * rows].reshape(1, m, rows, d)
                     else:
-                        grads[sub] = sg
-                else:
-                    grads[sub] = worker_gradients(sg, shard_all[sub],
-                                                  group_all[sub])
+                        Ar = A_b[sub, : m * rows].reshape(len(sub), m,
+                                                          rows, d)
+                    rr = resid[sub, : m * rows].reshape(len(sub), m, 1,
+                                                        rows)
+                    sg = shard_gradients(Ar, rr, rows,
+                                         trial_chunks(len(sub), item))
+                    if m == n_max and (group_all[sub] >= 0).all():
+                        # fast mode, nobody eliminated: worker w owns
+                        # shard w — the gather is the identity, skip it
+                        if sub.size == B:
+                            grads = sg
+                        else:
+                            grads[sub] = sg
+                    else:
+                        grads[sub] = worker_gradients(sg, shard_all[sub],
+                                                      group_all[sub])
 
         # -- Byzantine tampering (phase 1) --------------------------------
         hits = streams.phase1_hits(t, live) if has_byz else None
@@ -1134,7 +1216,7 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
                                           2 * max(1, int(f_t_arr[b])) + 1,
                                           st.rng)
                 rows = n_data // ai.num_shards
-                with obtrace.span("numpy.data"):
+                with obtrace.span("numpy.data", product="identify"):
                     Ar = (A0 if shared_problem else A_b[b])[
                         : ai.num_shards * rows]
                     Ar = Ar.reshape(1, ai.num_shards, rows, d)
@@ -1230,11 +1312,12 @@ def run_batch(specs: list[TrialSpec], *, backend: str = "numpy",
             tel_np["byz_active_steps"] += np.where(
                 live, (byz_mask & bstate.active).sum(axis=1), 0)
 
-        with obtrace.span("numpy.data"):
-            chunks = trial_chunks(B, item)
+        chunks = trial_chunks(B, item)
+        with obtrace.span("numpy.data", product="aggregate"):
             grad_upd = aggregate(agg_weight, grads, chunks)
             for b, v in voted.items():
                 grad_upd[b] = v
+        with obtrace.span("numpy.data", product="update"):
             W = _step(W, lr, grad_upd, live, chunks)
 
     # -- materialize per-trial results ------------------------------------

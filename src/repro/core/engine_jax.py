@@ -120,7 +120,9 @@ def build_schedule(specs: list[TrialSpec], mode: str = "auto") -> Schedule:
     tiny-problem full-engine replay (same schedule, kept as the parity
     oracle for "vector"); "oracle" forces the real-problem replay (a
     full numpy-engine pass — valid for every trial class, but the
-    replay then costs the thing it schedules); "auto" picks "vector"
+    replay then costs the thing it schedules; on a large shared problem
+    it runs the data products as GEMMs over the trials, since only its
+    control is read); "auto" picks "vector"
     whenever valid.  Mode "device" is not a host schedule: it is
     handled by ``run_batch_jax`` itself (the decisions come back from
     the on-device control plane and this host machinery replays *from
@@ -143,8 +145,11 @@ def build_schedule(specs: list[TrialSpec], mode: str = "auto") -> Schedule:
                           for s in specs]
         else:
             ctrl_specs = specs
+        # the device recomputes every value: only this pass's control is
+        # read, so its shared-problem products may run as GEMMs
         with obtrace.span("schedule.oracle", mode=mode):
-            control = run_batch(ctrl_specs, _recorder=rec)
+            control = run_batch(ctrl_specs, _recorder=rec,
+                                _control_only=True)
     with obtrace.span("schedule.stack"):
         keys = rec.steps[0].keys() if rec.steps else ()
         arrays = {k: np.stack([st[k] for st in rec.steps]) for k in keys}

@@ -102,6 +102,10 @@ def test_span_tree_of_an_oracle_sweep():
                         schedule="oracle")
     _each_inside(spans, "schedule.oracle", "engine.build_schedule")
     _each_inside(spans, "numpy.data", "schedule.oracle")
-    # residuals, phase-1 gradients, aggregate and update: three a step
-    assert sum(e["name"] == "numpy.data" for e in spans) >= 3 * 6
+    # residuals, phase-1 gradients, aggregate and update: four a step,
+    # each named by its product
+    data = [e for e in spans if e["name"] == "numpy.data"]
+    assert len(data) >= 4 * 6
+    assert {e["args"]["product"] for e in data} >= {
+        "residuals", "shard_gradients", "aggregate", "update"}
     assert not any(e["name"] == "schedule.replay" for e in spans)
